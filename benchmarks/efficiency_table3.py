@@ -32,6 +32,7 @@ import jax
 from benchmarks.common import print_table, save_table, with_kind
 from repro.attention import ShardSpec
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.layers.attention import plan_of
 from repro.models import lm
 
@@ -56,7 +57,7 @@ def _shard_plan_for(cfg, backend: str, *, causal: bool = True):
             "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
             f"(found {ndev} device)"
         )
-    mesh = jax.make_mesh((ndev,), ("seq",))
+    mesh = make_mesh((ndev,), ("seq",))
     return plan_of(cfg, causal=causal,
                    shard=ShardSpec(axis="seq", mesh=mesh)), None
 
@@ -195,6 +196,9 @@ def _parse_backends(arg: str) -> tuple:
 if __name__ == "__main__":
     import sys
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     backends = ("auto",)
     lens = None
     save_as = "efficiency_table3"

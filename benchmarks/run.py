@@ -23,6 +23,9 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma list: t2,t3,t4,t5,t6,t7,ablations,roofline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     quick = not args.full
     only = set(filter(None, args.only.split(",")))
 

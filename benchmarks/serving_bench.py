@@ -271,6 +271,9 @@ def run(*, slots: tuple = (2, 4), ctxs: tuple = (64, 128),
 if __name__ == "__main__":
     import sys
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     kw = {}
     argv = sys.argv[1:]
     if "--slots" in argv:

@@ -43,4 +43,7 @@ def run(*, quick: bool = True) -> dict:
 if __name__ == "__main__":
     import sys
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run(quick="--full" not in sys.argv)

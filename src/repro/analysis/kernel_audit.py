@@ -29,6 +29,7 @@ import math
 import pathlib
 
 import jax
+from jax.extend import core as jex_core
 
 from repro.analysis.kernel_grid import GRID, VJP_ENTRIES, GridEntry, VjpEntry
 from repro.analysis.lint import Finding
@@ -71,7 +72,8 @@ class KernelRecord:
 def _prod(shape) -> int:
     n = 1
     for s in shape:
-        n *= int(s) if isinstance(s, int) else 1  # mapped dims occupy 1
+        s = getattr(s, "block_size", s)  # pl.Blocked(n) -> n
+        n *= int(s) if isinstance(s, int) else 1  # squeezed dims occupy 1
     return n
 
 
@@ -85,12 +87,11 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(val):
-    core = jax.core
     vals = val if isinstance(val, (tuple, list)) else (val,)
     for v in vals:
-        if isinstance(v, core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
 
 
@@ -99,16 +100,20 @@ def _kernel_name(eqn) -> str:
     return getattr(info, "name", None) or str(info or "pallas_call")
 
 
-def _block_bytes(grid_mapping) -> tuple[int, int]:
-    ins = outs = 0
-    for bm in grid_mapping.block_mappings:
-        sdt = bm.array_shape_dtype
-        nbytes = _prod(bm.block_shape) * dtype_bytes(sdt.dtype)
-        if str(getattr(bm, "origin", "")).startswith("out"):
-            outs += nbytes
-        else:
-            ins += nbytes
-    return ins, outs
+def _block_bytes(eqn) -> tuple[int, int]:
+    """(input, output) block bytes of one ``pallas_call`` equation.
+
+    Block mappings follow the call's blocked operands in order — the
+    inputs after any scalar-prefetch operands, then the results — so each
+    block's dtype is its operand's.
+    """
+    gm = eqn.params["grid_mapping"]
+    first = gm.num_index_operands
+    operands = (list(eqn.invars[first:first + gm.num_inputs])
+                + list(eqn.outvars))
+    sizes = [_prod(bm.block_shape) * dtype_bytes(v.aval.dtype)
+             for bm, v in zip(gm.block_mappings, operands)]
+    return sum(sizes[:gm.num_inputs]), sum(sizes[gm.num_inputs:])
 
 
 def _scratch_bytes(eqn) -> int:
@@ -139,8 +144,7 @@ def trace_entry(entry: GridEntry) -> list[KernelRecord]:
     for eqn in _iter_eqns(closed.jaxpr):
         if eqn.primitive.name != "pallas_call":
             continue
-        gm = eqn.params["grid_mapping"]
-        bin_, bout = _block_bytes(gm)
+        bin_, bout = _block_bytes(eqn)
         records.append(KernelRecord(
             entry=entry.name,
             kernel=_kernel_name(eqn),

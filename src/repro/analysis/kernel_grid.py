@@ -134,18 +134,22 @@ def _fused_sums():
             _z((_BH, 1)), _z((_BH, _D, _DV)))
 
 
+def _decode_token():  # t, q, k, v in the decode kernels' row layout
+    return (_z((_BH,), jnp.int32), _z((_BH, _G, _D)), _z((_BH, 1, _D)),
+            _z((_BH, 1, _DV)))
+
+
 def _decode_args():
-    return (_z((_BH,)), _z((_BH, _G, _D)), _z((_BH, _D)), _z((_BH, _DV)),
-            _z((_BH, _D)), _z((_BH, _D)), _z((_BH, _D)), _z((_BH, _D)),
-            _z((_BH, 1)), _z((_BH, _D, _DV)))
+    return (*_decode_token(),
+            _z((_BH, 1, _D)), _z((_BH, 1, _D)), _z((_BH, 1, _D)),
+            _z((_BH, 1, _D)), _z((_BH, 1, 1)), _z((_BH, _D, _DV)))
 
 
 def _decode_q_args():
-    pay = tuple(_z((_BH, _D), jnp.int8) for _ in range(4))
-    sc = tuple(_z((_BH, 1)) for _ in range(4))
-    return (_z((_BH,)), _z((_BH, _G, _D)), _z((_BH, _D)), _z((_BH, _DV)),
-            pay, _z((_BH, _D, _DV), jnp.int8), sc, _z((_BH, 1)),
-            _z((_BH, 1)))
+    pay = tuple(_z((_BH, 1, _D), jnp.int8) for _ in range(4))
+    sc = tuple(_z((_BH, 1, 1)) for _ in range(4))
+    return (*_decode_token(), pay, _z((_BH, _D, _DV), jnp.int8), sc,
+            _z((_BH, 1, 1)), _z((_BH, 1, 1)))
 
 
 def _paged_pools():

@@ -1,8 +1,10 @@
 """Jit'd wrappers around the raw Pallas kernels in ``repro/kernels``.
 
 Shape policing + chunk adjustment live here so the kernels themselves stay
-pure grid/block code.  On CPU the kernels run in interpret mode; on TPU the
-compiled kernels keep the carried state in VMEM.  Calls route through the
+pure grid/block code.  On TPU the compiled kernels keep the carried state in
+VMEM; ``interpret=True`` runs them in the Pallas interpreter, which the
+registry asks for only when a Pallas backend is selected explicitly
+off-TPU.  Calls route through the
 ``attention/vjp.py`` custom-VJP rules, so ``jax.grad`` through these
 wrappers runs the Pallas backward kernels instead of raising.
 """
@@ -16,13 +18,11 @@ import jax.numpy as jnp
 from repro.attention.fused import effective_chunk, padded_len
 from repro.attention.vjp import flow_chunk_dot
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def chunked_causal_dot_pallas(
     qg: jax.Array, k: jax.Array, v: jax.Array, *, chunk: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """qg: (B, H, G, N, D); k: (B, H, N, D); v: (B, H, N, Dv).
 
@@ -30,7 +30,6 @@ def chunked_causal_dot_pallas(
     result sliced back — zero k/v rows contribute nothing to the causal
     aggregation, so no masking is needed inside the kernel.
     """
-    interp = _INTERPRET if interpret is None else interpret
     b, h, g, n, d = qg.shape
     dv = v.shape[-1]
     c = effective_chunk(n, chunk)
@@ -48,6 +47,6 @@ def chunked_causal_dot_pallas(
         pad(k.reshape(b * h, n, d)),
         pad(v.reshape(b * h, n, dv)),
         c,
-        interp,
+        interpret,
     )
     return out[:, :, :n].reshape(b, h, g, n, dv)

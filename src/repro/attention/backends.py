@@ -11,7 +11,8 @@ single-device backend is rejected with a "no collective glue" reason — so
 their position in the order never affects unsharded plans).
 
 Pallas backends only self-report applicable on TPU (interpret mode must be
-asked for explicitly); ``fused_causal`` carries the competition normalizer
+asked for explicitly, and their ops then take ``interpret=True`` from
+``registry.run_kwargs``); ``fused_causal`` carries the competition normalizer
 and the (D, Dv) aggregation state through one scan and is preferred over the
 multi-pass XLA paths wherever its contract (strict causal competition,
 chunkable length) holds; ``xla_cumsum`` accepts everything and is the
@@ -47,7 +48,7 @@ Array = jax.Array
 
 
 def _cumsum_dot(qg, k, v):
-    return causal_dot_grouped(qg, k, v, chunk_size=0, use_pallas=False)
+    return causal_dot_grouped(qg, k, v, chunk_size=0)
 
 
 def _check_causal_self(cfg: FlowConfig, shapes: ShapeInfo):
@@ -172,6 +173,7 @@ class PallasChunk(_ChunkedVerifyQuant, Backend):
 
     provides = frozenset({"forward", "prefill", "prefill_packed", "verify"})
     differentiable = frozenset({"forward", "prefill", "prefill_packed"})
+    pallas = True
 
     def supports(self, cfg, shapes, platform, *, op="forward", explicit=False):
         why = _check_causal_self(cfg, shapes)
@@ -192,22 +194,24 @@ class PallasChunk(_ChunkedVerifyQuant, Backend):
         # no grid launch is spent on it
         return pipeline.causal_verify(state, q, k, v, cfg)
 
-    def _dot(self, cfg):
+    def _dot(self, cfg, *, interpret=False):
         # the jit'd wrapper shrinks the chunk to divide N, so any shape that
         # passes supports() really runs the kernel (never a cumsum fallthrough)
         from repro.attention._pallas import chunked_causal_dot_pallas
 
         return functools.partial(chunked_causal_dot_pallas,
-                                 chunk=cfg.chunk_size)
+                                 chunk=cfg.chunk_size, interpret=interpret)
 
     # the Pallas kernel doubles as the cp shard-local inner strategy
     causal_dot_fn = _dot
 
-    def forward(self, q, k, v, cfg):
-        return pipeline.causal_forward(q, k, v, cfg, self._dot(cfg))
+    def forward(self, q, k, v, cfg, *, interpret=False):
+        return pipeline.causal_forward(q, k, v, cfg,
+                                       self._dot(cfg, interpret=interpret))
 
-    def prefill(self, q, k, v, cfg, *, lengths=None):
-        return pipeline.causal_forward(q, k, v, cfg, self._dot(cfg),
+    def prefill(self, q, k, v, cfg, *, lengths=None, interpret=False):
+        return pipeline.causal_forward(q, k, v, cfg,
+                                       self._dot(cfg, interpret=interpret),
                                        return_state=True, lengths=lengths)
 
 
@@ -218,6 +222,7 @@ class PallasNC(Backend):
 
     provides = frozenset({"forward"})
     differentiable = frozenset({"forward"})
+    pallas = True
 
     def supports(self, cfg, shapes, platform, *, op="forward", explicit=False):
         if cfg.causal:
@@ -232,10 +237,10 @@ class PallasNC(Backend):
             return False, "Pallas compiles on TPU only (interpret mode must be selected explicitly)"
         return True, "fused nc kernel"
 
-    def forward(self, q, k, v, cfg):
+    def forward(self, q, k, v, cfg, *, interpret=False):
         from repro.kernels.flow_nc import flow_attention_nc_pallas
 
-        return flow_attention_nc_pallas(q, k, v, cfg)
+        return flow_attention_nc_pallas(q, k, v, cfg, interpret=interpret)
 
 
 class PallasFused(_ChunkedVerifyQuant, Backend):
@@ -248,6 +253,7 @@ class PallasFused(_ChunkedVerifyQuant, Backend):
 
     provides = frozenset({"forward", "prefill", "prefill_packed", "verify"})
     differentiable = frozenset({"forward", "prefill"})
+    pallas = True
 
     def supports(self, cfg, shapes, platform, *, op="forward", explicit=False):
         why = _check_causal_self(cfg, shapes)
@@ -263,19 +269,19 @@ class PallasFused(_ChunkedVerifyQuant, Backend):
             return False, "Pallas compiles on TPU only (interpret mode must be selected explicitly)"
         return True, "fused strict-causal pallas kernel"
 
-    def forward(self, q, k, v, cfg):
+    def forward(self, q, k, v, cfg, *, interpret=False):
         from repro.kernels.flow_fused import flow_fused_forward
 
         k, v = pipeline.expand_kv(q, k, v, cfg)
-        out, _ = flow_fused_forward(q, k, v, cfg)
+        out, _ = flow_fused_forward(q, k, v, cfg, interpret=interpret)
         return out
 
-    def prefill(self, q, k, v, cfg, *, lengths=None):
+    def prefill(self, q, k, v, cfg, *, lengths=None, interpret=False):
         from repro.kernels.flow_fused import flow_fused_forward
 
         k, v = pipeline.expand_kv(q, k, v, cfg)
         return flow_fused_forward(q, k, v, cfg, return_state=True,
-                                  lengths=lengths)
+                                  lengths=lengths, interpret=interpret)
 
     def verify_step(self, state, q, k, v, cfg):
         # verify windows are tiny; the carry-in cumsum pass beats a kernel
@@ -370,6 +376,7 @@ class PallasDecode(Backend):
 
     provides = frozenset({"decode"})
     differentiable = frozenset()
+    pallas = True
 
     def supports(self, cfg, shapes, platform, *, op="forward", explicit=False):
         why = _check_state_ops(cfg, op)
@@ -392,17 +399,18 @@ class PallasDecode(Backend):
         return True, ("in-kernel dequantize/fp32-accumulate/requantize "
                       f"({why})")
 
-    def decode_step(self, state, q, k, v, cfg):
+    def decode_step(self, state, q, k, v, cfg, *, interpret=False):
         from repro.serving.quant import QuantizedPool
 
         k, v = pipeline.expand_kv(q, k, v, cfg)
         if isinstance(state, QuantizedPool):
             from repro.kernels.flow_decode import flow_decode_q_step
 
-            return flow_decode_q_step(state, q, k, v, cfg)
+            return flow_decode_q_step(state, q, k, v, cfg,
+                                      interpret=interpret)
         from repro.kernels.flow_decode import flow_decode_step
 
-        return flow_decode_step(state, q, k, v, cfg)
+        return flow_decode_step(state, q, k, v, cfg, interpret=interpret)
 
 
 register_backend("pallas_nc", PallasNC())

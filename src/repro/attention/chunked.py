@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.utils import varying_zeros
+
 Array = jax.Array
 
 
@@ -61,11 +63,7 @@ def chunked_causal_dot(q: Array, k: Array, v: Array, chunk_size: int) -> Array:
         )
         return new_state, (intra + inter).astype(q.dtype)
 
-    # zero-length contraction: free zeros that inherit shard_map varying axes
-    s0 = jnp.einsum(
-        "...jd,...je->...de", k[..., :0, :], v[..., :0, :],
-        preferred_element_type=jnp.float32,
-    )
+    s0 = varying_zeros((*k.shape[:-2], k.shape[-1], v.shape[-1]), k, v)
     _, outs = jax.lax.scan(step, s0, (qs, ks, vs))
     inv = tuple(range(1, len(batch) + 1)) + (0, len(batch) + 1, len(batch) + 2)
     return jnp.transpose(outs, inv).reshape(*batch, n, dv)
@@ -107,9 +105,6 @@ def chunked_causal_dot_grouped(
         )
         return new_state, (intra + inter).astype(qg.dtype)
 
-    s0 = jnp.einsum(
-        "bhjd,bhje->bhde", k[:, :, :0, :], v[:, :, :0, :],
-        preferred_element_type=jnp.float32,
-    )
+    s0 = varying_zeros((b, h, d, dv), k, v)
     _, outs = jax.lax.scan(step, s0, (qs, ks, vs))
     return jnp.moveaxis(outs, 0, 3).reshape(b, h, g, n, dv)

@@ -51,12 +51,8 @@ from repro.attention.registry import (
     ShardSpec,
     get_backend,
     list_backends,
+    run_kwargs,
 )
-
-# jax moved shard_map out of experimental in 0.5; support both
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 Array = jax.Array
 
@@ -349,7 +345,7 @@ class ContextParallelNC(_ContextParallel):
         k, v, _ = self._shard_shapes(q, k, v, cfg, shard)
         spec, _ = self._specs(shard)
 
-        @functools.partial(_shard_map, mesh=shard.mesh,
+        @functools.partial(jax.shard_map, mesh=shard.mesh,
                            in_specs=(spec, spec, spec), out_specs=spec)
         def wrapped(ql, kl, vl):
             return _nc_shard_body(ql, kl, vl, cfg, shard.axis)
@@ -407,14 +403,14 @@ class ContextParallelCausal(_ContextParallel):
         k, v, local = self._shard_shapes(q, k, v, cfg, shard)
         platform = jax.default_backend()
         inner = resolve_inner(cfg, local, platform, shard)
-        dot_fn = inner.causal_dot_fn(cfg)
+        dot_fn = inner.causal_dot_fn(cfg, **run_kwargs(inner, platform))
         spec, bspec = self._specs(shard)
         state_spec = FlowState(t=bspec, q_sum=bspec, k_sum=bspec,
                                ko_sum=bspec, qi_sum=bspec, z=bspec, s=bspec)
         out_specs = (spec, state_spec) if return_state else spec
         in_specs = (spec, spec, spec) + ((bspec,) if packed else ())
 
-        @functools.partial(_shard_map, mesh=shard.mesh, in_specs=in_specs,
+        @functools.partial(jax.shard_map, mesh=shard.mesh, in_specs=in_specs,
                            out_specs=out_specs)
         def wrapped(ql, kl, vl, *rest):
             lengths = rest[0] if rest else None

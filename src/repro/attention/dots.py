@@ -1,10 +1,10 @@
 """Causal-dot primitives with internal path selection.
 
 ``out_i = q_i . sum_{j<=i} k_j^T v_j`` is the aggregation shared by flow
-and plain linear attention.  These helpers are the ONLY place that chooses
-between the cumsum, chunked-scan and Pallas realizations of it — call sites
-(linear attention, context-parallel shards) pass a chunk size and get the
-best applicable path.
+and plain linear attention.  These helpers choose between its cumsum and
+chunked-scan XLA realizations from the shapes — call sites pass a chunk
+size and get the applicable path.  The Pallas realization is a registry
+backend (``pallas_chunk``), chosen by platform at resolution.
 """
 from __future__ import annotations
 
@@ -32,22 +32,13 @@ def causal_dot(q: Array, k: Array, v: Array, chunk_size: int = 128) -> Array:
 
 def causal_dot_grouped(
     qg: Array, k: Array, v: Array, chunk_size: int = 128,
-    *, platform: str | None = None, use_pallas: bool | None = None,
 ) -> Array:
     """Grouped causal dot sharing the carried state across the GQA group.
 
     qg: (B,Hkv,G,N,D); k: (B,Hkv,N,D); v: (B,Hkv,N,Dv) -> (B,Hkv,G,N,Dv).
-    ``use_pallas=None`` means "on TPU"; True forces the kernel (interpret
-    mode off-TPU), False forces XLA.
+    XLA only: the Pallas realization is the registry's ``pallas_chunk``.
     """
     n = qg.shape[-2]
-    if use_pallas is None:
-        platform = platform or jax.default_backend()
-        use_pallas = platform == "tpu"
-    if use_pallas and chunk_size and n % chunk_size == 0:
-        from repro.attention._pallas import chunked_causal_dot_pallas
-
-        return chunked_causal_dot_pallas(qg, k, v, chunk=chunk_size)
     if chunk_size and n % chunk_size == 0 and n > chunk_size:
         return chunked_causal_dot_grouped(qg, k, v, chunk_size)
     kv = jnp.einsum("bhnd,bhne->bhnde", k, v)

@@ -31,12 +31,54 @@ import dataclasses
 from typing import Any
 
 import jax
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core.flow_attention import FlowConfig
 from repro.attention import registry
 from repro.attention.registry import Backend, ShapeInfo, ShardSpec
+from repro.distribution.sharding import dp_axes
 
 Array = jax.Array
+
+
+def _run(be: Backend, platform: str, op, *args, cfg, **arrays):
+    """Call ``op(*args, cfg, **arrays)``, one of ``be``'s ops, on ``platform``
+    and on the traced mesh.
+
+    The compiler cannot partition a Pallas (Mosaic) kernel.  Under a mesh
+    context with more than one device on its ``Auto`` axes (the step
+    builders in ``launch/steps.py`` trace under
+    ``jax.sharding.use_abstract_mesh``) a Pallas op therefore runs in
+    ``jax.shard_map``: every array operand and result splits its leading
+    (batch) dim over the data-parallel axes, and its head dim over
+    ``model`` when every head count divides.  Flow ops never mix batch
+    rows or heads, so each device computes its own rows exactly.  XLA ops,
+    and every op with no such mesh, run as they are.
+    """
+    def fn(args, arrays):
+        return op(*args, cfg, **arrays, **registry.run_kwargs(be, platform))
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto and mesh.shape[a] > 1}
+    if not (be.pallas and auto):
+        return fn(args, arrays)
+    batch = tuple(a for a in dp_axes(mesh) if a in auto) or None
+    out = jax.eval_shape(fn, args, arrays)
+    leaves = jax.tree.leaves((args, arrays, out))
+    heads = ("model" if "model" in auto and all(
+        len(x.shape) < 2 or x.shape[1] % mesh.shape["model"] == 0
+        for x in leaves) else None)
+
+    def specs(tree):
+        return jax.tree.map(lambda x: P(*(batch, heads)[:len(x.shape)]),
+                            tree)
+
+    # the kernels' outputs carry no varying-axes type, so shard_map's vma
+    # check cannot type them
+    return jax.shard_map(fn, in_specs=specs((args, arrays)),
+                         out_specs=specs(out), check_vma=False)(args, arrays)
+
 
 _QUANT_DTYPES = ("int8", "fp8")
 
@@ -133,6 +175,12 @@ class BoundExecutor:
         """The plan's ``FlowConfig`` (set by construction)."""
         return self.plan.flow
 
+    @property
+    def platform(self) -> str:
+        """The platform ops resolve and run for: the plan's pin, else JAX's
+        default backend, read when an op is traced (never at import)."""
+        return self.plan.platform or jax.default_backend()
+
     def _shapes(self, q, k, v) -> ShapeInfo:
         return ShapeInfo.from_qkv(q, k, v)
 
@@ -153,7 +201,7 @@ class BoundExecutor:
         # is no sequence axis left to shard, and the O(d^2) state is
         # batch-led — both ops drop the plan's ShardSpec
         shard = None if op in ("decode", "verify") else p.shard
-        return registry.resolve(cfg, shapes, p.platform, op=op,
+        return registry.resolve(cfg, shapes, self.platform, op=op,
                                 needs_grad=p.needs_grad, shard=shard,
                                 quant=_quant_of(p, op))
 
@@ -166,7 +214,7 @@ class BoundExecutor:
         be = self.backend("forward", self._shapes(q, k, v))
         if self.plan.shard is not None:
             return be.forward(q, k, v, self.plan.flow, shard=self.plan.shard)
-        return be.forward(q, k, v, self.plan.flow)
+        return _run(be, self.platform, be.forward, q, k, v, cfg=self.plan.flow)
 
     def prefill(self, q: Array, k: Array, v: Array,
                 *, lengths: Array | None = None):
@@ -183,14 +231,16 @@ class BoundExecutor:
         if self.plan.shard is not None:
             return be.prefill(q, k, v, cfg, lengths=lengths,
                               shard=self.plan.shard)
-        return be.prefill(q, k, v, cfg, lengths=lengths)
+        return _run(be, self.platform, be.prefill, q, k, v, cfg=cfg,
+                    lengths=lengths)
 
     def decode_step(self, state, q: Array, k: Array, v: Array):
         """Advance one token on the O(d^2) recurrent state."""
         cfg = dataclasses.replace(self.plan.flow, causal=True,
                                   strict_causal=True)
         be = self.backend("decode", self._shapes(q, k, v))
-        return be.decode_step(state, q, k, v, cfg)
+        return _run(be, self.platform, be.decode_step, state, q, k, v,
+                    cfg=cfg)
 
     def verify_step(self, state, q: Array, k: Array, v: Array):
         """Score a drafted window of n tokens from ``state`` in one pass.
@@ -205,6 +255,7 @@ class BoundExecutor:
         cfg = dataclasses.replace(self.plan.flow, causal=True,
                                   strict_causal=True)
         be = self.backend("verify", self._shapes(q, k, v))
+        # every verify_step is the XLA carry-in pass: no kernel to interpret
         return be.verify_step(state, q, k, v, cfg)
 
 

@@ -134,6 +134,9 @@ class Backend:
     #: True for backends that ONLY make sense sharded (context-parallel
     #: glue); they are skipped for unsharded resolution requests.
     shard_only: bool = False
+    #: True for Pallas kernel strategies.  Their ops take ``interpret``,
+    #: which ``run_kwargs`` sets from the platform resolution ran for.
+    pallas: bool = False
 
     def supports(self, cfg: FlowConfig, shapes: ShapeInfo, platform: str,
                  *, op: str = "forward", explicit: bool = False):
@@ -282,9 +285,9 @@ def _candidates(cfg: FlowConfig) -> tuple[list, bool]:
     if sel == "auto":
         return list(_ORDER), False
     if sel == "xla":  # legacy: any non-Pallas strategy
-        return [n for n in _ORDER if not n.startswith("pallas")], False
+        return [n for n in _ORDER if not _REGISTRY[n].pallas], False
     if sel == "pallas":  # legacy: force a Pallas kernel (interpret off-TPU)
-        return [n for n in _ORDER if n.startswith("pallas")], True
+        return [n for n in _ORDER if _REGISTRY[n].pallas], True
     if sel in _REGISTRY:
         return [sel], True
     raise ValueError(
@@ -334,6 +337,16 @@ def _judge(be: Backend, cfg: FlowConfig, shapes: ShapeInfo, platform: str,
     if ok and shard_why:
         why = f"{why}; {shard_why}"
     return ok, why
+
+
+def run_kwargs(be: Backend, platform: str) -> dict:
+    """Keyword arguments ``be``'s ops run with on ``platform``.
+
+    A Pallas backend applies off-TPU only when selected explicitly
+    (``supports(..., explicit=True)``), and then runs in the Pallas
+    interpreter; on TPU its kernels compile.  Other backends take none.
+    """
+    return {"interpret": platform != "tpu"} if be.pallas else {}
 
 
 def resolve(cfg: FlowConfig, shapes: ShapeInfo, platform: str | None = None,

@@ -32,9 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
@@ -46,49 +43,53 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, dk_ref, dv_ref, u_ref, *,
     def _init():
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # (G, C, D)
-    k = k_ref[0].astype(jnp.float32)  # (C, D)
-    v = v_ref[0].astype(jnp.float32)  # (C, Dv)
-    g = g_ref[0].astype(jnp.float32)  # (G, C, Dv)
+    f32 = jnp.float32
+    grp = q_ref.shape[1]
+    # (G, C, X) panels flattened to (G*C, X): Mosaic contracts 2-D operands
+    # only, and summing over (g, i) is then one leading-dim contraction
+    q = q_ref[0].astype(f32).reshape(grp * chunk, -1)  # (G*C, D)
+    k = k_ref[0].astype(f32)  # (C, D)
+    v = v_ref[0].astype(f32)  # (C, Dv)
+    g = g_ref[0].astype(f32).reshape(grp * chunk, -1)  # (G*C, Dv)
 
     # mask[i, j] = 1 where i >= j: the transpose-time image of the fwd tril
-    mask = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
+    mask = jnp.tril(jnp.ones((chunk, chunk), f32))
 
-    # dk intra: scores_gv[g, i, j] = g[g, i] . v[j], masked to i >= j,
+    def masked(scores):  # (G*C, C) scores -> masked to i >= j per group
+        return (scores.reshape(grp, chunk, chunk) * mask).reshape(
+            grp * chunk, chunk)
+
+    def contract_rows(a, b):  # sum over the (g, i) rows: a^T b
+        return jax.lax.dot_general(
+            a, b, (((0,), (0,)), ((), ())), preferred_element_type=f32)
+
+    # dk intra: scores_gv[(g, i), j] = g[g, i] . v[j], masked to i >= j,
     # contracted against q over (g, i)
     scores_gv = jax.lax.dot_general(
-        g, v, (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (G, C, C)
-    dk = jax.lax.dot_general(
-        scores_gv * mask, q, (((0, 1), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (C_j, D)
+        g, v, (((1,), (1,)), ((), ())), preferred_element_type=f32
+    )  # (G*C, C)
+    dk = contract_rows(masked(scores_gv), q)  # (C_j, D)
 
-    # dv intra: scores_qk[g, i, j] = q[g, i] . k[j], masked, against g
+    # dv intra: scores_qk[(g, i), j] = q[g, i] . k[j], masked, against g
     scores_qk = jax.lax.dot_general(
-        q, k, (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (G, C, C)
-    dv = jax.lax.dot_general(
-        scores_qk * mask, g, (((0, 1), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (C_j, Dv)
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
+    )  # (G*C, C)
+    dv = contract_rows(masked(scores_qk), g)  # (C_j, Dv)
 
     # inter-chunk terms from the reverse carry U (later chunks only)
     u = u_ref[...]  # (D, Dv)
     dk += jax.lax.dot_general(
-        v, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        v, u, (((1,), (1,)), ((), ())), preferred_element_type=f32
     )  # (C, D): dk[j] += U @ v[j]
     dv += jax.lax.dot_general(
-        k, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        k, u, (((1,), (0,)), ((), ())), preferred_element_type=f32
     )  # (C, Dv): dv[j] += U^T k[j]
 
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     # fold this chunk into the carry before stepping to the EARLIER chunk
-    u_ref[...] += jax.lax.dot_general(
-        q, g, (((0, 1), (0, 1)), ((), ())), preferred_element_type=jnp.float32
-    )  # (D, Dv)
+    u_ref[...] += contract_rows(q, g)  # (D, Dv)
 
 
 def flow_chunk_dkv_call(
@@ -130,7 +131,7 @@ def flow_chunk_dkv_call(
         ],
         scratch_shapes=[pltpu.VMEM((d, dv_dim), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(q, k, v, g)

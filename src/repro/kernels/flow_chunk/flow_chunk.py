@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
@@ -81,7 +78,7 @@ def flow_chunk_call(
         out_shape=jax.ShapeDtypeStruct((bh, g, n, dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((d, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(q, k, v)

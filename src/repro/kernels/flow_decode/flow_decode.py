@@ -14,14 +14,17 @@ State arrays are aliased input->output (``input_output_aliases``) so the
 pool updates in place: a decode step allocates nothing per token, which is
 what lets the serving Worker keep thousands of slots device-resident.
 
-Shapes (BH = slots * Hkv, G = grouped query heads per kv head):
+Shapes (BH = slots * Hkv, G = grouped query heads per kv head).  Per-row
+vectors carry a unit middle axis so every block's last two dims equal the
+array's, as the TPU tiling rule requires of a one-row block:
 
-    tf          (BH, 1)  f32  position count AFTER this token (t+1), SMEM
+    t           (BH,)    i32  position count AFTER this token (t+1);
+                              scalar prefetch, so it lives in SMEM
     q           (BH, G, D)    raw (pre-phi) grouped queries
-    k           (BH, D)       raw key
-    v           (BH, Dv)      value
-    k/q/ko/qi_sum (BH, D) f32 running flow sums        (aliased in-place)
-    z           (BH, 1)  f32  competition normalizer   (aliased in-place)
+    k           (BH, 1, D)    raw key
+    v           (BH, 1, Dv)   value
+    k/q/ko/qi_sum (BH, 1, D) f32 running flow sums     (aliased in-place)
+    z           (BH, 1, 1) f32 competition normalizer  (aliased in-place)
     s           (BH, D, Dv) f32 aggregation state      (aliased in-place)
     out         (BH, G, Dv)   attention output for this token
 
@@ -40,27 +43,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flow_attention import phi_map
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
-def _kernel(tf_ref, q_ref, k_ref, v_ref, ksum_ref, qsum_ref, kosum_ref,
+def _kernel(t_ref, q_ref, k_ref, v_ref, ksum_ref, qsum_ref, kosum_ref,
             qisum_ref, z_ref, s_ref,
             out_ref, ksum_o, qsum_o, kosum_o, qisum_o, z_o, s_o,
             *, g: int, eps: float, phi: str, use_allocation: bool):
-    tf = tf_ref[0]  # f32 scalar: t+1 for this slot
+    tf = t_ref[pl.program_id(0)].astype(jnp.float32)  # t+1 for this row
 
     phi_q = phi_map(q_ref[0].astype(jnp.float32), phi)  # (G, D)
-    phi_k = phi_map(k_ref[...].astype(jnp.float32), phi)  # (1, D)
-    vf = v_ref[...].astype(jnp.float32)  # (1, Dv)
+    phi_k = phi_map(k_ref[0].astype(jnp.float32), phi)  # (1, D)
+    vf = v_ref[0].astype(jnp.float32)  # (1, Dv)
 
     normal_k = tf  # sources seen so far
     normal_q = tf * g  # sinks seen so far (G per position)
 
-    k_sum = ksum_ref[...] + phi_k  # (1, D)
-    q_sum = qsum_ref[...] + jnp.sum(phi_q, axis=0, keepdims=True)
+    k_sum = ksum_ref[0] + phi_k  # (1, D)
+    q_sum = qsum_ref[0] + jnp.sum(phi_q, axis=0, keepdims=True)
 
     sink_in = normal_k / jax.lax.dot_general(
         phi_q + eps, k_sum + eps, (((1,), (1,)), ((), ())),
@@ -68,21 +68,21 @@ def _kernel(tf_ref, q_ref, k_ref, v_ref, ksum_ref, qsum_ref, kosum_ref,
     )  # (G, 1)
     src_out = normal_q / jnp.sum((phi_k + eps) * (q_sum + eps))  # scalar
 
-    ko_sum = kosum_ref[...] + phi_k * src_out
+    ko_sum = kosum_ref[0] + phi_k * src_out
     cons_sink = jax.lax.dot_general(
         phi_q + eps, ko_sum + eps, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) / normal_q  # (G, 1)
 
     q_in = phi_q * sink_in  # value-normalized queries (G, D)
-    qi_sum = qisum_ref[...] + jnp.sum(q_in, axis=0, keepdims=True)
+    qi_sum = qisum_ref[0] + jnp.sum(q_in, axis=0, keepdims=True)
     cons_src = jnp.sum((phi_k + eps) * (qi_sum + eps)) / normal_k
     cons_src = jnp.clip(cons_src, -1.0, 1.0)
 
     alloc = jax.nn.sigmoid(cons_sink) if use_allocation else 1.0
 
     e = jnp.exp(cons_src)  # bounded in [1/e, e] by the clamp
-    z = z_ref[...] + e  # (1, 1)
+    z = z_ref[0] + e  # (1, 1)
     s = s_ref[0] + jax.lax.dot_general(
         phi_k, vf * e, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -94,62 +94,51 @@ def _kernel(tf_ref, q_ref, k_ref, v_ref, ksum_ref, qsum_ref, kosum_ref,
     )  # (G, Dv)
     out_ref[0] = (agg * (normal_k / z[0, 0]) * alloc).astype(out_ref.dtype)
 
-    ksum_o[...] = k_sum
-    qsum_o[...] = q_sum
-    kosum_o[...] = ko_sum
-    qisum_o[...] = qi_sum
-    z_o[...] = z
-    s_o[0] = s
+    for ref, val in zip((ksum_o, qsum_o, kosum_o, qisum_o, z_o, s_o),
+                        (k_sum, q_sum, ko_sum, qi_sum, z, s)):
+        ref[0] = val
 
 
 def flow_decode_call(
-    tf: Array, q: Array, k: Array, v: Array,
+    t: Array, q: Array, k: Array, v: Array,
     k_sum: Array, q_sum: Array, ko_sum: Array, qi_sum: Array,
     z: Array, s: Array,
     *, eps: float, phi: str, use_allocation: bool, interpret: bool = False,
 ):
     """One decode step over the flattened (BH = slots*Hkv) state pool.
 
-    Returns (out (BH, G, Dv), k_sum, q_sum, ko_sum, qi_sum, z, s) with the
-    six state arrays updated in place (aliased buffers).
+    Shapes as in the module docstring.  Returns (out (BH, G, Dv), k_sum,
+    q_sum, ko_sum, qi_sum, z, s) with the six state arrays updated in
+    place (aliased buffers).
     """
     bh, g, d = q.shape
     dv = v.shape[-1]
-    row = lambda b: (b, 0)  # noqa: E731 — (1, X) row block of a (BH, X) array
-    row3 = lambda b: (b, 0, 0)  # noqa: E731
-    state_specs = [
-        pl.BlockSpec((1, d), row),  # k_sum
-        pl.BlockSpec((1, d), row),  # q_sum
-        pl.BlockSpec((1, d), row),  # ko_sum
-        pl.BlockSpec((1, d), row),  # qi_sum
-        pl.BlockSpec((1, 1), row),  # z
-        pl.BlockSpec((1, d, dv), row3),  # s
-    ]
+    row = lambda b, t: (b, 0, 0)  # noqa: E731 — one (slot, head) row
+    state_specs = [pl.BlockSpec((1, 1, d), row)] * 4 + [
+        pl.BlockSpec((1, 1, 1), row), pl.BlockSpec((1, d, dv), row)]
     f32 = jnp.float32
-    state_shapes = [
-        jax.ShapeDtypeStruct((bh, d), f32),
-        jax.ShapeDtypeStruct((bh, d), f32),
-        jax.ShapeDtypeStruct((bh, d), f32),
-        jax.ShapeDtypeStruct((bh, d), f32),
-        jax.ShapeDtypeStruct((bh, 1), f32),
-        jax.ShapeDtypeStruct((bh, d, dv), f32),
-    ]
+    state_shapes = [jax.ShapeDtypeStruct((bh, 1, d), f32)] * 4 + [
+        jax.ShapeDtypeStruct((bh, 1, 1), f32),
+        jax.ShapeDtypeStruct((bh, d, dv), f32)]
     return pl.pallas_call(
         functools.partial(_kernel, g=g, eps=eps, phi=phi,
                           use_allocation=use_allocation),
-        grid=(bh,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, d), row3),
-            pl.BlockSpec((1, d), row),
-            pl.BlockSpec((1, dv), row),
-            *state_specs,
-        ],
-        out_specs=[pl.BlockSpec((1, g, dv), row3), *state_specs],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh,),
+            in_specs=[
+                pl.BlockSpec((1, g, d), row),
+                pl.BlockSpec((1, 1, d), row),
+                pl.BlockSpec((1, 1, dv), row),
+                *state_specs,
+            ],
+            out_specs=[pl.BlockSpec((1, g, dv), row), *state_specs],
+        ),
         out_shape=[jax.ShapeDtypeStruct((bh, g, dv), q.dtype), *state_shapes],
         # state inputs 4..9 alias state outputs 1..6: the pool is updated
         # in place, no per-token allocation
         input_output_aliases={4: 1, 5: 2, 6: 3, 7: 4, 8: 5, 9: 6},
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(tf.reshape(bh), q, k, v, k_sum, q_sum, ko_sum, qi_sum, z, s)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(t.astype(jnp.int32), q, k, v, k_sum, q_sum, ko_sum, qi_sum, z, s)

@@ -2,7 +2,9 @@
 
 Reshapes the (B, Hkv, ...) state pool and the (B, Hq, 1, D) token into the
 kernel's flattened (BH, ...) layout, launches one grid over every
-(slot, kv head) pair, and reassembles the ``FlowState``.  GQA grouping
+(slot, kv head) pair, and reassembles the ``FlowState``.  ``interpret``
+runs the kernel in the Pallas interpreter; the registry sets it only for a
+Pallas backend selected explicitly off-TPU.  GQA grouping
 ("shared" mode) is native: the G query heads of a kv group ride along as
 the kernel's G axis; "expand" mode is handled by the backend expanding kv
 heads before calling (G becomes 1).
@@ -18,44 +20,49 @@ from repro.attention.recurrent import FlowState
 from repro.core.flow_attention import FlowConfig
 from repro.kernels.flow_decode.flow_decode import flow_decode_call
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 Array = jax.Array
+
+
+def _row_t(t: Array, hkv: int) -> Array:
+    """(B,) per-slot counts -> (B*Hkv,) per-(slot, head) row counts."""
+    return jnp.repeat(t.astype(jnp.int32), hkv)
+
+
+def _rows(q: Array, k: Array, v: Array):
+    """The token's q/k/v in the kernel's row layout: (BH, G, D),
+    (BH, 1, D), (BH, 1, Dv)."""
+    b, hq, _, d = q.shape
+    hkv = k.shape[1]
+    return (q[:, :, 0].reshape(b * hkv, hq // hkv, d),
+            k.reshape(b * hkv, 1, d), v.reshape(b * hkv, 1, v.shape[-1]))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def flow_decode_step(
     state: FlowState, q: Array, k: Array, v: Array, cfg: FlowConfig,
-    *, interpret: bool | None = None,
+    *, interpret: bool = False,
 ) -> tuple[FlowState, Array]:
     """Advance one token for every slot.
 
     q: (B, Hq, 1, D); k: (B, Hkv, 1, D); v: (B, Hkv, 1, Dv).
     Returns (new_state, out (B, Hq, 1, Dv)).
     """
-    interp = _INTERPRET if interpret is None else interpret
     b, hq, one, d = q.shape
     assert one == 1, "decode_step consumes exactly one position"
     hkv = k.shape[1]
-    g = hq // hkv
     dv = v.shape[-1]
     bh = b * hkv
 
     t = state.t + 1  # (B,) int32, per-slot position counts
-    tf = jnp.broadcast_to(
-        t.astype(jnp.float32)[:, None], (b, hkv)
-    ).reshape(bh, 1)
-    qg = q[:, :, 0].reshape(b, hkv, g, d).reshape(bh, g, d)
-    k2 = k[:, :, 0].reshape(bh, d)
-    v2 = v[:, :, 0].reshape(bh, dv)
+    qg, k2, v2 = _rows(q, k, v)
+    rows = lambda x: x.reshape(bh, 1, -1)  # noqa: E731 — (BH, 1, X) rows
 
     out, k_sum, q_sum, ko_sum, qi_sum, z, s = flow_decode_call(
-        tf, qg, k2, v2,
-        state.k_sum.reshape(bh, d), state.q_sum.reshape(bh, d),
-        state.ko_sum.reshape(bh, d), state.qi_sum.reshape(bh, d),
-        state.z.reshape(bh, 1), state.s.reshape(bh, d, dv),
+        _row_t(t, hkv), qg, k2, v2,
+        rows(state.k_sum), rows(state.q_sum), rows(state.ko_sum),
+        rows(state.qi_sum), rows(state.z), state.s.reshape(bh, d, dv),
         eps=cfg.eps, phi=cfg.phi, use_allocation=cfg.use_allocation,
-        interpret=interp,
+        interpret=interpret,
     )
     new_state = FlowState(
         t=t,
@@ -72,7 +79,7 @@ def flow_decode_step(
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def flow_decode_q_step(
     pool, q: Array, k: Array, v: Array, cfg: FlowConfig,
-    *, interpret: bool | None = None,
+    *, interpret: bool = False,
 ):
     """Advance one token for every slot of a *quantized* FlowState pool.
 
@@ -84,7 +91,6 @@ def flow_decode_q_step(
     """
     from repro.kernels.flow_decode.quant import flow_decode_q_call
 
-    interp = _INTERPRET if interpret is None else interpret
     assert pool.granularity == "head" and pool.exempt == ("z",), (
         "flow_decode_q_step expects the serving FlowState pool recipe "
         f"(head granularity, z exempt); got {pool.granularity!r}/"
@@ -93,30 +99,22 @@ def flow_decode_q_step(
     b, hq, one, d = q.shape
     assert one == 1, "decode_step consumes exactly one position"
     hkv = k.shape[1]
-    g = hq // hkv
     dv = v.shape[-1]
     bh = b * hkv
 
     t = st.t + 1  # (B,) int32, per-slot position counts
-    tf = jnp.broadcast_to(
-        t.astype(jnp.float32)[:, None], (b, hkv)
-    ).reshape(bh, 1)
-    qg = q[:, :, 0].reshape(b, hkv, g, d).reshape(bh, g, d)
-    k2 = k[:, :, 0].reshape(bh, d)
-    v2 = v[:, :, 0].reshape(bh, dv)
+    qg, k2, v2 = _rows(q, k, v)
+    rows = lambda x: x.reshape(bh, 1, -1)  # noqa: E731 — (BH, 1, X) rows
 
     out, pays, s_pay, scs, s_sc, z = flow_decode_q_call(
-        tf, qg, k2, v2,
-        (st.k_sum.reshape(bh, d), st.q_sum.reshape(bh, d),
-         st.ko_sum.reshape(bh, d), st.qi_sum.reshape(bh, d)),
+        _row_t(t, hkv), qg, k2, v2,
+        (rows(st.k_sum), rows(st.q_sum), rows(st.ko_sum), rows(st.qi_sum)),
         st.s.reshape(bh, d, dv),
-        (sc.k_sum.reshape(bh, 1), sc.q_sum.reshape(bh, 1),
-         sc.ko_sum.reshape(bh, 1), sc.qi_sum.reshape(bh, 1)),
-        sc.s.reshape(bh, 1),
-        st.z.reshape(bh, 1),
+        (rows(sc.k_sum), rows(sc.q_sum), rows(sc.ko_sum), rows(sc.qi_sum)),
+        rows(sc.s), rows(st.z),
         eps=cfg.eps, phi=cfg.phi, use_allocation=cfg.use_allocation,
         qmax=pool.spec.qmax, is_int=pool.spec.name == "int8",
-        interpret=interp,
+        interpret=interpret,
     )
     new_payload = FlowState(
         t=t,

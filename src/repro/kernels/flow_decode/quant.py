@@ -17,12 +17,11 @@ decode step allocates nothing per token.
 
 The competition normalizer ``z`` stays raw fp32 (it is a monotone
 running sum — quantizing it would accumulate rounding into every future
-denominator); it is (BH, 1), so its bytes are noise next to the panel.
+denominator); it is (BH, 1, 1), so its bytes are noise next to the panel.
 
-Tile-shape caveat: like the full-precision kernel this uses (1, X) row
-blocks, below the int8 minimum native tile (32, 128) — Mosaic pads
-sub-tile blocks, and CI exercises this kernel in interpret mode; the
-cross-(slot, head) layout keeps HBM reads contiguous either way.
+Layout is the full-precision kernel's: per-row vectors and scales carry a
+unit middle axis ((BH, 1, D), (BH, 1, 1)) so each one-row block's last two
+dims equal the array's, and ``t`` arrives by scalar prefetch in SMEM.
 """
 from __future__ import annotations
 
@@ -34,8 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flow_attention import phi_map
-
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 Array = jax.Array
 _SCALE_EPS = 1e-12  # matches serving.quant's amax floor
@@ -53,7 +50,7 @@ def _requant(x, qmax: float, is_int: bool, dtype):
     return payload, sc
 
 
-def _kernel(tf_ref, q_ref, k_ref, v_ref,
+def _kernel(t_ref, q_ref, k_ref, v_ref,
             ksum_p, qsum_p, kosum_p, qisum_p, s_p,
             ksum_s, qsum_s, kosum_s, qisum_s, s_s, z_ref,
             out_ref,
@@ -61,19 +58,19 @@ def _kernel(tf_ref, q_ref, k_ref, v_ref,
             ksum_so, qsum_so, kosum_so, qisum_so, s_so, z_o,
             *, g: int, eps: float, phi: str, use_allocation: bool,
             qmax: float, is_int: bool):
-    tf = tf_ref[0]  # f32 scalar: t+1 for this slot
+    tf = t_ref[pl.program_id(0)].astype(jnp.float32)  # t+1 for this row
 
     # dequantize this (slot, head)'s state in VMEM: payload * scale
-    deq = lambda p_ref, s_ref: p_ref[...].astype(jnp.float32) * s_ref[0, 0]  # noqa: E731
+    deq = lambda p_ref, s_ref: p_ref[0].astype(jnp.float32) * s_ref[0]  # noqa: E731
     ksum = deq(ksum_p, ksum_s)  # (1, D)
     qsum = deq(qsum_p, qsum_s)
     kosum = deq(kosum_p, kosum_s)
     qisum = deq(qisum_p, qisum_s)
-    s_in = s_p[0].astype(jnp.float32) * s_s[0, 0]  # (D, Dv)
+    s_in = deq(s_p, s_s)  # (D, Dv)
 
     phi_q = phi_map(q_ref[0].astype(jnp.float32), phi)  # (G, D)
-    phi_k = phi_map(k_ref[...].astype(jnp.float32), phi)  # (1, D)
-    vf = v_ref[...].astype(jnp.float32)  # (1, Dv)
+    phi_k = phi_map(k_ref[0].astype(jnp.float32), phi)  # (1, D)
+    vf = v_ref[0].astype(jnp.float32)  # (1, Dv)
 
     normal_k = tf  # sources seen so far
     normal_q = tf * g  # sinks seen so far (G per position)
@@ -102,7 +99,7 @@ def _kernel(tf_ref, q_ref, k_ref, v_ref,
     alloc = jax.nn.sigmoid(cons_sink) if use_allocation else 1.0
 
     e = jnp.exp(cons_src)  # bounded in [1/e, e] by the clamp
-    z = z_ref[...] + e  # (1, 1)
+    z = z_ref[0] + e  # (1, 1)
     s = s_in + jax.lax.dot_general(
         phi_k, vf * e, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -120,16 +117,16 @@ def _kernel(tf_ref, q_ref, k_ref, v_ref,
         (ko_sum, kosum_po, kosum_so), (qi_sum, qisum_po, qisum_so),
     ):
         payload, sc = _requant(val, qmax, is_int, p_out.dtype)
-        p_out[...] = payload
-        s_out[...] = jnp.reshape(sc, (1, 1))
+        p_out[0] = payload
+        s_out[0] = jnp.reshape(sc, (1, 1))
     s_payload, s_sc = _requant(s, qmax, is_int, s_po.dtype)
     s_po[0] = s_payload
-    s_so[...] = jnp.reshape(s_sc, (1, 1))
-    z_o[...] = z
+    s_so[0] = jnp.reshape(s_sc, (1, 1))
+    z_o[0] = z
 
 
 def flow_decode_q_call(
-    tf: Array, q: Array, k: Array, v: Array,
+    t: Array, q: Array, k: Array, v: Array,
     sum_payloads, s_payload: Array, sum_scales, s_scale: Array, z: Array,
     *, eps: float, phi: str, use_allocation: bool,
     qmax: float, is_int: bool, interpret: bool = False,
@@ -137,48 +134,51 @@ def flow_decode_q_call(
     """One quantized decode step over the flattened (BH) state pool.
 
     ``sum_payloads`` / ``sum_scales`` — 4-tuples (k, q, ko, qi order);
-    payloads (BH, D) low-bit, scales (BH, 1) f32, s payload (BH, D, Dv),
-    s scale (BH, 1), z (BH, 1) raw f32.  Returns
+    payloads (BH, 1, D) low-bit, scales (BH, 1, 1) f32, s payload
+    (BH, D, Dv), s scale (BH, 1, 1), z (BH, 1, 1) raw f32; ``t`` (BH,)
+    int32 and q/k/v as in ``flow_decode_call``.  Returns
     (out, (payloads...), s_payload, (scales...), s_scale, z) with every
     state buffer updated in place (aliased).
     """
     bh, g, d = q.shape
     dv = v.shape[-1]
-    row = lambda b: (b, 0)  # noqa: E731
-    row3 = lambda b: (b, 0, 0)  # noqa: E731
+    row = lambda b, t: (b, 0, 0)  # noqa: E731 — one (slot, head) row
     qdt = sum_payloads[0].dtype
     f32 = jnp.float32
-    pay_specs = [pl.BlockSpec((1, d), row)] * 4 + [
-        pl.BlockSpec((1, d, dv), row3)]
-    pay_shapes = [jax.ShapeDtypeStruct((bh, d), qdt)] * 4 + [
+    pay_specs = [pl.BlockSpec((1, 1, d), row)] * 4 + [
+        pl.BlockSpec((1, d, dv), row)]
+    pay_shapes = [jax.ShapeDtypeStruct((bh, 1, d), qdt)] * 4 + [
         jax.ShapeDtypeStruct((bh, d, dv), qdt)]
-    sc_specs = [pl.BlockSpec((1, 1), row)] * 5
-    sc_shapes = [jax.ShapeDtypeStruct((bh, 1), f32)] * 5
-    z_spec = pl.BlockSpec((1, 1), row)
+    sc_specs = [pl.BlockSpec((1, 1, 1), row)] * 5
+    sc_shapes = [jax.ShapeDtypeStruct((bh, 1, 1), f32)] * 5
+    z_spec = pl.BlockSpec((1, 1, 1), row)
     res = pl.pallas_call(
         functools.partial(_kernel, g=g, eps=eps, phi=phi,
                           use_allocation=use_allocation,
                           qmax=qmax, is_int=is_int),
-        grid=(bh,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, d), row3),
-            pl.BlockSpec((1, d), row),
-            pl.BlockSpec((1, dv), row),
-            *pay_specs, *sc_specs, z_spec,
-        ],
-        out_specs=[pl.BlockSpec((1, g, dv), row3), *pay_specs, *sc_specs,
-                   z_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh,),
+            in_specs=[
+                pl.BlockSpec((1, g, d), row),
+                pl.BlockSpec((1, 1, d), row),
+                pl.BlockSpec((1, 1, dv), row),
+                *pay_specs, *sc_specs, z_spec,
+            ],
+            out_specs=[pl.BlockSpec((1, g, dv), row), *pay_specs, *sc_specs,
+                       z_spec],
+        ),
         out_shape=[jax.ShapeDtypeStruct((bh, g, dv), q.dtype), *pay_shapes,
-                   *sc_shapes, jax.ShapeDtypeStruct((bh, 1), f32)],
+                   *sc_shapes, jax.ShapeDtypeStruct((bh, 1, 1), f32)],
         # payload inputs 4..8 -> outputs 1..5, scale inputs 9..13 ->
         # outputs 6..10, z input 14 -> output 11: the whole quantized
         # pool updates in place
         input_output_aliases={4: 1, 5: 2, 6: 3, 7: 4, 8: 5, 9: 6, 10: 7,
                               11: 8, 12: 9, 13: 10, 14: 11},
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(tf.reshape(bh), q, k, v, *sum_payloads, s_payload, *sum_scales,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(t.astype(jnp.int32), q, k, v, *sum_payloads, s_payload, *sum_scales,
       s_scale, z)
     return (res[0], tuple(res[1:5]), res[5], tuple(res[6:10]), res[10],
             res[11])

@@ -27,13 +27,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flow_fused import _CompilerParams, _chunk_step, _phi as phi_map
+from .flow_fused import (_chunk_step, _phi as phi_map, _positions,
+                         state_specs)
 
 Array = jax.Array
 
 
 def _bwd_kernel(
-    q_ref, k_ref, v_ref, lens_ref,
+    lens_ref, q_ref, k_ref, v_ref,
     tq_ref, tk_ref, tko_ref, tqi_ref, tz_ref, ts_ref,
     go_ref, gq_ref, gk_ref, gko_ref, gqi_ref, gz_ref, gs_ref,
     dq_ref, dk_ref, dv_ref,
@@ -50,20 +51,13 @@ def _bwd_kernel(
         for ref in (q_suf, k_suf, ko_suf, qi_suf, z_suf, s_suf):
             ref[...] = jnp.zeros_like(ref)
         # carried state cotangent starts from the FlowState output grads
-        dq_c[...] = gq_ref[...]
-        dk_c[...] = gk_ref[...]
-        dko_c[...] = gko_ref[...]
-        dqi_c[...] = gqi_ref[...]
-        dz_c[...] = gz_ref[...]
-        ds_c[...] = gs_ref[0]
+        for c_ref, g_ref in zip((dq_c, dk_c, dko_c, dqi_c, dz_c, ds_c),
+                                (gq_ref, gk_ref, gko_ref, gqi_ref, gz_ref,
+                                 gs_ref)):
+            c_ref[...] = g_ref[0]
 
     f32 = jnp.float32
-    pos = (
-        ci * chunk
-        + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        + 1
-    ).astype(f32)
-    valid = (pos <= lens_ref[...]).astype(f32)
+    pos, valid = _positions(lens_ref, ci, chunk)
     ltri = jnp.tril(jnp.ones((chunk, chunk), f32))
     normal_k = pos
     normal_q = pos * float(grp)
@@ -82,8 +76,8 @@ def _bwd_kernel(
     # --- reconstruct the carry that entered this chunk ------------------
     k_inc = jnp.sum(pk, axis=0, keepdims=True)  # (1, D)
     q_inc = jnp.sum(pq.sum(axis=0), axis=0, keepdims=True)
-    k_run = tk_ref[...] - k_suf[...] - k_inc
-    q_run = tq_ref[...] - q_suf[...] - q_inc
+    k_run = tk_ref[0] - k_suf[...] - k_inc
+    q_run = tq_ref[0] - q_suf[...] - q_inc
     k_csum = k_run + csum(pk)
     q_csum = q_run + csum(pq.sum(axis=0))
     sink_in = normal_k[None] / jnp.sum(
@@ -96,8 +90,8 @@ def _bwd_kernel(
     qi_inc = jnp.sum(
         (pq * sink_in).sum(axis=0), axis=0, keepdims=True
     )
-    ko_run = tko_ref[...] - ko_suf[...] - ko_inc
-    qi_run = tqi_ref[...] - qi_suf[...] - qi_inc
+    ko_run = tko_ref[0] - ko_suf[...] - ko_inc
+    qi_run = tqi_ref[0] - qi_suf[...] - qi_inc
     qi_csum = qi_run + csum((pq * sink_in).sum(axis=0))
     cons_src = jnp.clip(
         jnp.sum((pk + eps) * (qi_csum + eps), axis=-1, keepdims=True)
@@ -107,7 +101,7 @@ def _bwd_kernel(
     )
     e = jnp.exp(cons_src) * valid
     z_inc = jnp.sum(e, axis=0, keepdims=True)  # (1, 1)
-    z_run = tz_ref[...] - z_suf[...] - z_inc
+    z_run = tz_ref[0] - z_suf[...] - z_inc
     s_inc = jax.lax.dot_general(
         pk, vc * e, (((0,), (0,)), ((), ())), preferred_element_type=f32
     )
@@ -154,67 +148,57 @@ def flow_fused_bwd_call(
     """Gradients of ``flow_fused_call`` w.r.t. (q, k, v).
 
     ``totals``/``g_sums`` are the six forward state outputs and their
-    cotangents, each (BH, D) / (BH, 1) / (BH, D, Dv) f32.  Returns
+    cotangents, each (BH, D) / (BH, 1) / (BH, D, Dv) f32.  ``lens`` (BH,)
+    int32 rides in SMEM by scalar prefetch.  Returns
     (dq, dk, dv) with the primal dtypes.
     """
     bh, grp, n, d = q.shape
     dv_dim = v.shape[-1]
     assert n % chunk == 0, (n, chunk)
     nc = n // chunk
-    lens_f = lens.astype(jnp.float32).reshape(bh, 1)
+    f32 = jnp.float32
 
-    def rev_g(b, r):
+    def rev_g(b, r, lens):
         return (b, 0, nc - 1 - r, 0)
 
-    def rev(b, r):
+    def rev(b, r, lens):
         return (b, nc - 1 - r, 0)
 
-    def fixed(b, r):
-        return (b, 0)
+    def as_rows(sums):  # (BH, D)/(BH, 1) -> the kernel's (BH, 1, X) rows
+        return [x if x.ndim == 3 else x.reshape(bh, 1, x.shape[-1])
+                for x in sums]
 
-    sum_spec = pl.BlockSpec((1, d), fixed)
-    s_spec = pl.BlockSpec((1, d, dv_dim), lambda b, r: (b, 0, 0))
-    z_spec = pl.BlockSpec((1, 1), fixed)
-    outs = pl.pallas_call(
+    state = state_specs(d, dv_dim, lambda b, r, lens: (b, 0, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_kernel, nc=nc, chunk=chunk, eps=eps,
                           phi=phi, use_alloc=use_alloc, grp=grp),
-        grid=(bh, nc),
-        in_specs=[
-            pl.BlockSpec((1, grp, chunk, d), rev_g),
-            pl.BlockSpec((1, chunk, d), rev),
-            pl.BlockSpec((1, chunk, dv_dim), rev),
-            z_spec,
-            sum_spec, sum_spec, sum_spec, sum_spec, z_spec, s_spec,
-            pl.BlockSpec((1, grp, chunk, dv_dim), rev_g),
-            sum_spec, sum_spec, sum_spec, sum_spec, z_spec, s_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, grp, chunk, d), rev_g),
-            pl.BlockSpec((1, chunk, d), rev),
-            pl.BlockSpec((1, chunk, dv_dim), rev),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nc),
+            in_specs=[
+                pl.BlockSpec((1, grp, chunk, d), rev_g),
+                pl.BlockSpec((1, chunk, d), rev),
+                pl.BlockSpec((1, chunk, dv_dim), rev),
+                *state,
+                pl.BlockSpec((1, grp, chunk, dv_dim), rev_g),
+                *state,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, grp, chunk, d), rev_g),
+                pl.BlockSpec((1, chunk, d), rev),
+                pl.BlockSpec((1, chunk, dv_dim), rev),
+            ],
+            scratch_shapes=2 * ([pltpu.VMEM((1, d), f32)] * 4 + [
+                pltpu.VMEM((1, 1), f32), pltpu.VMEM((d, dv_dim), f32)]),
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((d, dv_dim), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((d, dv_dim), jnp.float32),
-        ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(q, k, v, lens_f, *totals, g_out, *g_sums)
-    return outs
+    )(lens.astype(jnp.int32), q, k, v, *as_rows(totals), g_out,
+      *as_rows(g_sums))

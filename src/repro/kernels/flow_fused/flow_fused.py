@@ -18,7 +18,8 @@ every intermediate is chunk-sized.  Chunk-local inclusive cumsums are
 ``tril @ x`` matmuls so the identical step function differentiates cleanly
 under ``jax.vjp`` inside the backward kernel (``bwd.py``).
 
-Per-row validity is a (BH, 1) ``lens`` input: positions past a row's
+Per-row validity is a (BH,) int32 ``lens`` scalar-prefetch operand (SMEM):
+positions past a row's
 length contribute ZERO to phi_q/phi_k/e, so every running sum freezes at
 the boundary and the final carry IS that row's boundary ``FlowState`` —
 one mechanism serves both tail padding (awkward lengths) and right-padded
@@ -32,9 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 Array = jax.Array
 
@@ -51,6 +49,26 @@ def _phi(x, kind: str):
     if kind == "relu":
         return jax.nn.relu(x)
     raise ValueError(f"unknown phi {kind!r}")
+
+
+def state_specs(d: int, dv: int, index_map):
+    """Blocks of the six per-row FlowState pieces, in carry order.
+
+    The four flow sums travel as (BH, 1, D) and ``z`` as (BH, 1, 1) so each
+    block's last two dims equal the array's (the TPU tiling rule); ``s``
+    is (BH, D, Dv).  ``index_map`` maps grid indices to (row, 0, 0).
+    """
+    return ([pl.BlockSpec((1, 1, d), index_map)] * 4
+            + [pl.BlockSpec((1, 1, 1), index_map),
+               pl.BlockSpec((1, d, dv), index_map)])
+
+
+def state_shapes(bh: int, d: int, dv: int):
+    """f32 array shapes matching ``state_specs``."""
+    f32 = jnp.float32
+    return ([jax.ShapeDtypeStruct((bh, 1, d), f32)] * 4
+            + [jax.ShapeDtypeStruct((bh, 1, 1), f32),
+               jax.ShapeDtypeStruct((bh, d, dv), f32)])
 
 
 def _chunk_step(runs, qc, kc, vc, *, pos, valid, ltri, eps: float, phi: str,
@@ -114,19 +132,23 @@ def _chunk_step(runs, qc, kc, vc, *, pos, valid, ltri, eps: float, phi: str,
     z = z_run + csum(e)  # (C, 1)
     v_w = vf * e  # (C, Dv)
 
-    # (4) aggregation: intra-chunk tril matmul + carried (D, Dv) state
-    q_in = pq * sink_in  # (G, C, D)
+    # (4) aggregation: intra-chunk tril matmul + carried (D, Dv) state.
+    # The dots run on (G*C, .) panels: Mosaic contracts 2-D operands only,
+    # and the backward's jax.vjp transposes them into 2-D dots too.
+    g, c, d = pq.shape
+    q_in = (pq * sink_in).reshape(g * c, d)
     scores = jax.lax.dot_general(
-        q_in, pk, (((2,), (1,)), ((), ())), preferred_element_type=f32
-    )  # (G, C, C)
+        q_in, pk, (((1,), (1,)), ((), ())), preferred_element_type=f32
+    )  # (G*C, C)
+    scores = (scores.reshape(g, c, c) * ltri).reshape(g * c, c)
     intra = jax.lax.dot_general(
-        scores * ltri, v_w, (((2,), (0,)), ((), ())),
-        preferred_element_type=f32,
-    )  # (G, C, Dv)
+        scores, v_w, (((1,), (0,)), ((), ())), preferred_element_type=f32,
+    )  # (G*C, Dv)
     inter = jax.lax.dot_general(
-        q_in, s, (((2,), (0,)), ((), ())), preferred_element_type=f32
-    )  # (G, C, Dv)
-    out = (intra + inter) * (normal_k / z)[None] * alloc
+        q_in, s, (((1,), (0,)), ((), ())), preferred_element_type=f32
+    )  # (G*C, Dv)
+    out = ((intra + inter).reshape(g, c, -1) * (normal_k / z)[None]
+           * alloc)
 
     new_runs = (
         q_csum[-1:],
@@ -141,7 +163,15 @@ def _chunk_step(runs, qc, kc, vc, *, pos, valid, ltri, eps: float, phi: str,
     return new_runs, out
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, lens_ref, o_ref, qs_ref, ks_ref,
+def _positions(lens_ref, ci, chunk: int):
+    """(pos, valid), each (C, 1) f32: 1-based global positions of chunk
+    ``ci`` and the in-row mask against this row's SMEM length."""
+    pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) + 1
+    valid = (pos <= lens_ref[pl.program_id(0)]).astype(jnp.float32)
+    return pos.astype(jnp.float32), valid
+
+
+def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, qs_ref, ks_ref,
                 kos_ref, qis_ref, zo_ref, so_ref, q_run, k_run, ko_run,
                 qi_run, z_run, s_run, *, chunk: int, eps: float, phi: str,
                 use_alloc: bool, grp: int):
@@ -152,12 +182,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, lens_ref, o_ref, qs_ref, ks_ref,
         for ref in (q_run, k_run, ko_run, qi_run, z_run, s_run):
             ref[...] = jnp.zeros_like(ref)
 
-    pos = (
-        ci * chunk
-        + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        + 1
-    ).astype(jnp.float32)
-    valid = (pos <= lens_ref[...]).astype(jnp.float32)  # (C,1) vs (1,1)
+    pos, valid = _positions(lens_ref, ci, chunk)
     ltri = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
 
     runs = (q_run[...], k_run[...], ko_run[...], qi_run[...], z_run[...],
@@ -172,12 +197,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, lens_ref, o_ref, qs_ref, ks_ref,
         ref[...] = val
     # state outputs: fixed blocks, rewritten every chunk — the final
     # (sequential) write is the boundary FlowState
-    qs_ref[...] = new_runs[0]
-    ks_ref[...] = new_runs[1]
-    kos_ref[...] = new_runs[2]
-    qis_ref[...] = new_runs[3]
-    zo_ref[...] = new_runs[4]
-    so_ref[0] = new_runs[5]
+    for ref, val in zip((qs_ref, ks_ref, kos_ref, qis_ref, zo_ref, so_ref),
+                        new_runs):
+        ref[0] = val
 
 
 def flow_fused_call(
@@ -198,49 +220,36 @@ def flow_fused_call(
     dv = v.shape[-1]
     assert n % chunk == 0, (n, chunk)
     nc = n // chunk
-    lens_f = lens.astype(jnp.float32).reshape(bh, 1)
-
-    def fixed(b, c):
-        return (b, 0)
-
-    sum_spec = pl.BlockSpec((1, d), fixed)
+    f32 = jnp.float32
+    state = state_specs(d, dv, lambda b, c, lens: (b, 0, 0))
     outs = pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, eps=eps, phi=phi,
                           use_alloc=use_alloc, grp=grp),
-        grid=(bh, nc),
-        in_specs=[
-            pl.BlockSpec((1, grp, chunk, d), lambda b, c: (b, 0, c, 0)),
-            pl.BlockSpec((1, chunk, d), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1), fixed),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, grp, chunk, dv), lambda b, c: (b, 0, c, 0)),
-            sum_spec, sum_spec, sum_spec, sum_spec,
-            pl.BlockSpec((1, 1), fixed),
-            pl.BlockSpec((1, d, dv), lambda b, c: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, grp, n, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, d, dv), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((d, dv), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nc),
+            in_specs=[
+                pl.BlockSpec((1, grp, chunk, d),
+                             lambda b, c, lens: (b, 0, c, 0)),
+                pl.BlockSpec((1, chunk, d), lambda b, c, lens: (b, c, 0)),
+                pl.BlockSpec((1, chunk, dv), lambda b, c, lens: (b, c, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, grp, chunk, dv),
+                             lambda b, c, lens: (b, 0, c, 0)),
+                *state,
+            ],
+            scratch_shapes=[pltpu.VMEM((1, d), f32)] * 4 + [
+                pltpu.VMEM((1, 1), f32), pltpu.VMEM((d, dv), f32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bh, grp, n, dv), q.dtype),
+                   *state_shapes(bh, d, dv)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(q, k, v, lens_f)
+    )(lens.astype(jnp.int32), q, k, v)
     out, q_sum, k_sum, ko_sum, qi_sum, z, s = outs
-    return out, (q_sum, k_sum, ko_sum, qi_sum, z, s)
+    return out, (q_sum.reshape(bh, d), k_sum.reshape(bh, d),
+                 ko_sum.reshape(bh, d), qi_sum.reshape(bh, d),
+                 z.reshape(bh, 1), s)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
-_INTERPRET = None  # resolved per-call: non-TPU backends interpret
-
 
 def _pad_chunk(x, n_pad: int):
     n = x.shape[-2]
@@ -34,7 +32,7 @@ def _pad_chunk(x, n_pad: int):
 def flow_fused_forward(
     q: Array, k: Array, v: Array, cfg, *,
     return_state: bool = False, lengths: Optional[Array] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Strict-causal Flow-Attention via the fused Pallas kernel.
 
@@ -42,14 +40,13 @@ def flow_fused_forward(
     grouped layout contract (Hq divisible by Hkv).  ``lengths`` (B,) int32
     selects the forward-only packed path whose returned state is each
     row's boundary FlowState.  Non-chunk-multiple N is padded and masked,
-    never shrunk to degenerate chunks.
+    never shrunk to degenerate chunks.  ``interpret`` runs the kernels in
+    the Pallas interpreter (off-TPU).
     """
     # lazy: this package must import before repro.attention finishes
     from repro.attention.recurrent import FlowState
     from repro.core.flow_attention import _group, _ungroup
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b, hq, n, d = q.shape
     hkv = k.shape[1]
     dv = v.shape[-1]

@@ -20,16 +20,16 @@ import jax
 
 from repro.core.flow_attention import FlowConfig, _group
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def flow_attention_nc_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    cfg: FlowConfig = FlowConfig(), *, interpret: bool | None = None,
+    cfg: FlowConfig = FlowConfig(), *, interpret: bool = False,
 ) -> jax.Array:
-    """q: (B,Hq,N,D); k,v: (B,Hkv,M,*) -> (B,Hq,N,Dv)."""
-    interp = _INTERPRET if interpret is None else interpret
+    """q: (B,Hq,N,D); k,v: (B,Hkv,M,*) -> (B,Hq,N,Dv).
+
+    ``interpret`` runs the kernel in the Pallas interpreter (off-TPU).
+    """
     b, hq, n, d = q.shape
     hkv, m = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -47,6 +47,6 @@ def flow_attention_nc_pallas(
         cfg.eps,
         256,
         cfg.use_competition,
-        interp,
+        interpret,
     )
     return out.reshape(b, hkv, g, n, dv).reshape(b, hq, n, dv)

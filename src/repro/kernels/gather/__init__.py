@@ -1,4 +1,7 @@
-from repro.kernels.gather.boundary import boundary_gather
-from repro.kernels.gather.paged import paged_gather, paged_gather_quant
+from repro.kernels.gather.boundary import boundary_gather, boundary_gather_xla
+from repro.kernels.gather.paged import (paged_gather, paged_gather_quant,
+                                        paged_gather_quant_xla,
+                                        paged_gather_xla)
 
-__all__ = ["boundary_gather", "paged_gather", "paged_gather_quant"]
+__all__ = ["boundary_gather", "boundary_gather_xla", "paged_gather",
+           "paged_gather_quant", "paged_gather_quant_xla", "paged_gather_xla"]

@@ -13,6 +13,9 @@ wrap, so each tap is loaded at its index clipped into range and then
 zero-masked where the true index is below zero (the fresh-conv left
 pad).  ``k`` is tiny (conv_width <= 4 in every config), so the per-tap
 python loop unrolls to a handful of loads.
+
+``boundary_gather_xla`` is the XLA form, for platforms without the kernel;
+the caller picks one by platform.
 """
 from __future__ import annotations
 
@@ -25,8 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 def _kernel(lens_ref, x_ref, o_ref, *, k: int):
     b = pl.program_id(0)
@@ -35,28 +36,28 @@ def _kernel(lens_ref, x_ref, o_ref, *, k: int):
     taps = []
     for jj in range(k - 1):
         idx = start + jj
-        row = pl.load(x_ref, (pl.ds(0, 1), pl.ds(jnp.clip(idx, 0, n - 1), 1),
-                              slice(None)))  # (1, 1, W)
+        row = x_ref[:, pl.ds(jnp.clip(idx, 0, n - 1), 1), :]  # (1, 1, W)
         taps.append(jnp.where(idx >= 0, row, jnp.zeros_like(row)))
     o_ref[...] = jnp.concatenate(taps, axis=1).astype(o_ref.dtype)
 
 
+def boundary_gather_xla(xb: Array, lengths: Array, k: int) -> Array:
+    """``boundary_gather`` as an XLA pad + ``take_along_axis``."""
+    bsz, _, w = xb.shape
+    pad = jnp.zeros((bsz, k - 1, w), xb.dtype)
+    xp = jnp.concatenate([pad, xb], axis=1)
+    idx = lengths.astype(jnp.int32)[:, None] + jnp.arange(k - 1)[None, :]
+    return jnp.take_along_axis(xp, idx[..., None], axis=1)
+
+
 def boundary_gather(xb: Array, lengths: Array, k: int, *,
-                    interpret: bool | None = None) -> Array:
+                    interpret: bool = False) -> Array:
     """xb: (B, N, W); lengths: (B,) int.  Returns (B, k-1, W): row i's
     trailing ``k-1`` inputs before position ``lengths[i]``, zero-filled on
-    the left exactly like a fresh causal-conv pad."""
+    the left exactly like a fresh causal-conv pad.  ``interpret`` runs the
+    kernel in the Pallas interpreter (off-TPU)."""
     bsz, n, w = xb.shape
     lens = lengths.astype(jnp.int32)
-
-    if interpret is None and _INTERPRET:
-        # off-TPU serving keeps the XLA pad+gather; tests opt into the
-        # kernel with ``interpret=True``
-        pad = jnp.zeros((bsz, k - 1, w), xb.dtype)
-        xp = jnp.concatenate([pad, xb], axis=1)
-        idx = lens[:, None] + jnp.arange(k - 1)[None, :]
-        return jnp.take_along_axis(xp, idx[..., None], axis=1)
-    interp = bool(interpret)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -68,5 +69,5 @@ def boundary_gather(xb: Array, lengths: Array, k: int, *,
         functools.partial(_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, k - 1, w), xb.dtype),
-        interpret=interp,
+        interpret=interpret,
     )(lens, xb)

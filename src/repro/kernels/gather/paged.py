@@ -9,7 +9,9 @@ block ``(b, j)`` of the output is fetched straight from pool page
 the attention kernel wants.  One pass, no transpose.
 
 Sentinel page ids (== num_pages) clip into an arbitrary real page, same
-as the XLA gather's clamp; callers mask the tail via ``kv_len``.
+as the XLA gather's clamp; callers mask the tail via ``kv_len``.  The
+``*_xla`` functions are that XLA gather, for platforms without the kernel;
+the caller picks one by platform.
 """
 from __future__ import annotations
 
@@ -20,8 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 def _kernel(tbl_ref, k_ref, v_ref, ko_ref, vo_ref):
     del tbl_ref  # only consumed by the index maps
@@ -29,24 +29,37 @@ def _kernel(tbl_ref, k_ref, v_ref, ko_ref, vo_ref):
     vo_ref[...] = v_ref[...]
 
 
+def _flat_pages(pool: Array, table: Array) -> Array:
+    """XLA gather of ``table``'s (clamped) pages as (B, Hkv, MP*page, X)."""
+    p, hkv, page, x = pool.shape
+    b, mp = table.shape
+    g = pool[jnp.clip(table, 0, p - 1)]  # (B, MP, Hkv, page, X)
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, mp * page, x)
+
+
+def paged_gather_xla(kc: Array, vc: Array, table: Array):
+    """``paged_gather`` as a plain XLA gather (same clamped semantics)."""
+    return _flat_pages(kc, table), _flat_pages(vc, table)
+
+
+def paged_gather_quant_xla(kc: Array, vc: Array, ks: Array, vs: Array,
+                           table: Array, *, out_dtype):
+    """``paged_gather_quant`` as XLA: dequantize the pools, then gather."""
+    def deq(pool, spool):
+        return (pool.astype(jnp.float32) * spool).astype(out_dtype)
+    return paged_gather_xla(deq(kc, ks), deq(vc, vs), table)
+
+
 def paged_gather(kc: Array, vc: Array, table: Array, *,
-                 interpret: bool | None = None) -> tuple[Array, Array]:
+                 interpret: bool = False) -> tuple[Array, Array]:
     """Gather pool pages into per-slot sequences.
 
     kc/vc: (P, Hkv, page, D|Dv) pools; table: (B, MP) int32 page ids.
-    Returns (kg, vg) shaped (B, Hkv, MP*page, D|Dv)."""
+    Returns (kg, vg) shaped (B, Hkv, MP*page, D|Dv).  ``interpret`` runs
+    the kernel in the Pallas interpreter (off-TPU)."""
     p, hkv, page, d = kc.shape
     dv = vc.shape[-1]
     b, mp = table.shape
-
-    if interpret is None and _INTERPRET:
-        # off-TPU serving stays on the plain XLA gather (same clamped
-        # semantics); tests opt into the kernel with ``interpret=True``
-        def flat(pool, dd):
-            g = pool[jnp.clip(table, 0, p - 1)]  # (B, MP, Hkv, page, dd)
-            return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, mp * page, dd)
-        return flat(kc, d), flat(vc, dv)
-    interp = bool(interpret)
 
     def src(b_, j, tbl):
         return (jnp.clip(tbl[b_, j], 0, p - 1), 0, 0, 0)
@@ -73,7 +86,7 @@ def paged_gather(kc: Array, vc: Array, table: Array, *,
             jax.ShapeDtypeStruct((b, hkv, mp * page, d), kc.dtype),
             jax.ShapeDtypeStruct((b, hkv, mp * page, dv), vc.dtype),
         ],
-        interpret=interp,
+        interpret=interpret,
     )(table.astype(jnp.int32), kc, vc)
 
 
@@ -89,7 +102,7 @@ def _kernel_quant(tbl_ref, k_ref, v_ref, ks_ref, vs_ref, ko_ref, vo_ref):
 
 def paged_gather_quant(kc: Array, vc: Array, ks: Array, vs: Array,
                        table: Array, *, out_dtype,
-                       interpret: bool | None = None) -> tuple[Array, Array]:
+                       interpret: bool = False) -> tuple[Array, Array]:
     """Gather + dequantize quantized pool pages into per-slot sequences.
 
     kc/vc: (P, Hkv, page, D|Dv) low-bit payload pools; ks/vs:
@@ -101,15 +114,6 @@ def paged_gather_quant(kc: Array, vc: Array, ks: Array, vs: Array,
     p, hkv, page, d = kc.shape
     dv = vc.shape[-1]
     b, mp = table.shape
-
-    if interpret is None and _INTERPRET:
-        def flat(pool, spool, dd):
-            idx = jnp.clip(table, 0, p - 1)
-            g = pool[idx].astype(jnp.float32) * spool[idx]
-            return (g.transpose(0, 2, 1, 3, 4)
-                    .reshape(b, hkv, mp * page, dd).astype(out_dtype))
-        return flat(kc, ks, d), flat(vc, vs, dv)
-    interp = bool(interpret)
 
     def src(b_, j, tbl):
         return (jnp.clip(tbl[b_, j], 0, p - 1), 0, 0, 0)
@@ -138,5 +142,5 @@ def paged_gather_quant(kc: Array, vc: Array, ks: Array, vs: Array,
             jax.ShapeDtypeStruct((b, hkv, mp * page, d), out_dtype),
             jax.ShapeDtypeStruct((b, hkv, mp * page, dv), out_dtype),
         ],
-        interpret=interp,
+        interpret=interpret,
     )(table.astype(jnp.int32), kc, vc, ks, vs)

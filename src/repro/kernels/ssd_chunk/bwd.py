@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ssd_chunk import _CompilerParams, _ssd_step
+from .ssd_chunk import _ssd_step
 
 Array = jax.Array
 
@@ -91,7 +91,7 @@ def ssd_chunk_bwd_call(
         ],
         scratch_shapes=[pltpu.VMEM((p, s), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(x, dta, b, c, hins, g)

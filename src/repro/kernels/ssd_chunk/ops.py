@@ -15,8 +15,6 @@ import jax.numpy as jnp
 
 from repro.kernels.ssd_chunk.ssd_chunk import ssd_chunk_call
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def ssd_chunk_dot(x: jax.Array, dta: jax.Array, b: jax.Array, c: jax.Array,
@@ -49,15 +47,15 @@ ssd_chunk_dot.defvjp(_ssd_fwd, _ssd_bwd)
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(
     xh: jax.Array, dt: jax.Array, bmat: jax.Array, cmat: jax.Array,
-    a: jax.Array, *, chunk: int = 128, interpret: bool | None = None,
+    a: jax.Array, *, chunk: int = 128, interpret: bool = False,
 ) -> jax.Array:
     """Head-batched SSD scan.
 
     xh: (B, N, H, P); dt: (B, N, H) fp32 (softplus already applied);
     bmat/cmat: (B, N, S) shared across heads; a: (H,) negative.
     Returns y: (B, N, H, P) fp32 (without the D-skip term).
+    ``interpret`` runs the kernel in the Pallas interpreter (off-TPU).
     """
-    interp = _INTERPRET if interpret is None else interpret
     bsz, n, h, p = xh.shape
     s = bmat.shape[-1]
     c = min(chunk, n)
@@ -70,5 +68,5 @@ def ssd_scan_pallas(
     bm = jnp.broadcast_to(bmat[:, None], (bsz, h, n, s)).reshape(bsz * h, n, s)
     cm = jnp.broadcast_to(cmat[:, None], (bsz, h, n, s)).reshape(bsz * h, n, s)
 
-    y = ssd_chunk_dot(x, dta, bm, cm, c, interp)
+    y = ssd_chunk_dot(x, dta, bm, cm, c, interpret)
     return y.reshape(bsz, h, n, p).transpose(0, 2, 1, 3)

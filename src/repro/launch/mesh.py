@@ -7,21 +7,28 @@ outer ring) while ``model`` stays intra-pod (ICI).
 
 These are FUNCTIONS, not module constants — importing this module never
 touches jax device state (required by the dry-run contract).
+
+Every mesh here has ``Auto`` axes: the sharding rules
+(``with_sharding_constraint`` in ``launch/steps.py``, the context-parallel
+``shard_map`` glue) are written for the compiler to propagate shardings,
+whereas ``jax.make_mesh`` defaults to ``Explicit`` axes.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for tests / elastic re-meshing."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """Mesh with ``Auto`` axes over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_fleet_meshes(prefill: int, decode: int, devices=None):

@@ -33,10 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.layers.attention import plan_of
 from repro.models import lm
 from repro.serving.engine import Engine, PagedSpec, Request
 from repro.serving.fleet import FleetEngine
+from repro.utils import device_summary
 
 
 def _parse_fleet(spec: str) -> tuple[int, int]:
@@ -94,6 +96,7 @@ def main():
     if args.fleet and args.speculate_k:
         raise SystemExit("--fleet serves plain decode only (speculative "
                          "windows stay a single-engine feature)")
+    configure_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.attn:
@@ -126,7 +129,8 @@ def main():
                         plan=plan, dtype=dtype, draft=args.draft,
                         speculate_k=args.speculate_k)
         worker0 = engine.worker
-    print(f"[serve] attention plan: {worker0.plan.describe()}")
+    print(f"[serve] attention plan: {worker0.plan.describe()}, "
+          f"{device_summary()}")
     print(f"[serve] dtypes: activations={args.dtype} "
           f"state_pools={args.state_dtype or args.dtype}")
     rng = np.random.default_rng(0)

@@ -36,6 +36,18 @@ def _dp_spec_axis(dp):
     return tuple(dp) if len(dp) > 1 else (dp[0] if dp else None)
 
 
+def _on_mesh(fn, mesh):
+    """``fn`` traced under ``mesh``'s abstract mesh: Pallas attention ops
+    inside then run once per device (``attention/plan.py``), since the
+    compiler cannot partition a Pallas kernel itself."""
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+
+    return traced
+
+
 def model_loss_fn(cfg: ModelConfig, xplan=None):
     from repro.models import encdec, lm
 
@@ -188,6 +200,11 @@ def build_train_step(cfg: ModelConfig, shape: ShapeSpec, mesh,
         for f in state_shape.opt._fields
     ])
     state_specs = TrainState(master=zspecs, opt=opt_specs, step=P())
+    # the state as the step holds it: a first state placed with these
+    # shardings has the type of every later one, so the step compiles once
+    state_shape = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        state_shape, to_shardings(state_specs, mesh))
 
     binputs = train_inputs(cfg, shape)
     bspec = batch_spec(mesh, shape.global_batch)
@@ -196,7 +213,7 @@ def build_train_step(cfg: ModelConfig, shape: ShapeSpec, mesh,
     )
 
     jit_step = jax.jit(
-        step_fn,
+        _on_mesh(step_fn, mesh),
         in_shardings=(to_shardings(state_specs, mesh),
                       to_shardings(batch_specs, mesh)),
         out_shardings=(to_shardings(state_specs, mesh), None),
@@ -275,7 +292,7 @@ def build_prefill_step(cfg: ModelConfig, shape: ShapeSpec, mesh,
         lambda x: P(*(list(bspec) + [None] * (x.ndim - 2))[: x.ndim]), binputs
     )
     jit_step = jax.jit(
-        prefill_fn,
+        _on_mesh(prefill_fn, mesh),
         in_shardings=(to_shardings(pspecs, mesh),
                       to_shardings(batch_specs, mesh)),
     )
@@ -351,7 +368,7 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeSpec, mesh,
         batch_specs["key"] = P(None)  # the PRNG key is replicated, never
         # batch-sharded (its leading dim can coincide with tiny batches)
     jit_step = jax.jit(
-        decode_fn,
+        _on_mesh(decode_fn, mesh),
         in_shardings=(to_shardings(pspecs, mesh),
                       to_shardings(batch_specs, mesh)),
     )

@@ -427,7 +427,8 @@ def _attention_decode(
     pool = cache if isinstance(cache, quant_lib.QuantizedPool) else None
     store = pool.payload if pool is not None else cache
     if isinstance(store, PagedKVCache):
-        return _paged_decode(params, q, k, v, cache, cfg, page_table)
+        return _paged_decode(params, q, k, v, cache, cfg, page_table,
+                             mixer_lib.plan_platform(plan))
 
     if kind == "flow":
         # quantized pools pass straight through: the registry decode op is
@@ -493,14 +494,20 @@ def _attention_decode(
 
 
 def _paged_decode(params, q, k, v, cache, cfg: ModelConfig,
-                  page_table: Array | None):
+                  page_table: Array | None, platform: str):
     """Softmax decode on the paged pool: scatter this token's K/V into the
     slot's current page, attend over the gathered page sequence.
 
     ``cache`` may be a ``QuantizedPool`` over a ``PagedKVCache``: the
     token's rows quantize once on append (per-token scales scatter into a
     mirrored scale pool) and the page-table gather dequantizes inline
-    (``paged_gather_quant``)."""
+    (``paged_gather_quant``).  On TPU the page-table gathers are Pallas
+    kernels writing the (B, Hkv, MP*page, D) layout directly; on any other
+    ``platform`` they are plain XLA gathers."""
+    # flowlint: disable=FL001 -- utility gathers below the registry; the plan's platform picks one
+    from repro.kernels import gather
+
+    on_tpu = platform == "tpu"
     assert page_table is not None, "paged decode requires the page table"
     pool = cache if isinstance(cache, quant_lib.QuantizedPool) else None
     store = pool.payload if pool is not None else cache
@@ -523,25 +530,18 @@ def _paged_decode(params, q, k, v, cache, cfg: ModelConfig,
         vc = store.v.at[pid, :, off].set(vq)
         ksc = pool.scale.k.at[pid, :, off].set(ks)
         vsc = pool.scale.v.at[pid, :, off].set(vs)
-        # flowlint: disable=FL001 -- utility gather below the registry; self-falls-back off-TPU
-        from repro.kernels.gather import paged_gather_quant
-
-        kg, vg = paged_gather_quant(kc, vc, ksc, vsc, page_table,
-                                    out_dtype=q.dtype)
+        gather_q = (gather.paged_gather_quant if on_tpu
+                    else gather.paged_gather_quant_xla)
+        kg, vg = gather_q(kc, vc, ksc, vsc, page_table, out_dtype=q.dtype)
         new_cache = pool.with_state(PagedKVCache(kc, vc, t + 1),
                                     PagedKVCache(ksc, vsc, pool.scale.pos))
     else:
         kc = store.k.at[pid, :, off].set(k[:, :, 0].astype(store.k.dtype))
         vc = store.v.at[pid, :, off].set(v[:, :, 0].astype(store.v.dtype))
         # logical per-slot cache = its pages in table order; sentinel
-        # gathers clamp into garbage that kv_len masks off.  On TPU the
-        # page-table gather is a Pallas kernel writing the
-        # (B, Hkv, MP*page, D) layout directly; off-TPU it stays a plain
-        # XLA gather.
-        # flowlint: disable=FL001 -- utility gather below the registry; self-falls-back off-TPU
-        from repro.kernels.gather import paged_gather
-
-        kg, vg = paged_gather(kc, vc, page_table)
+        # gathers clamp into garbage that kv_len masks off
+        gather_fn = gather.paged_gather if on_tpu else gather.paged_gather_xla
+        kg, vg = gather_fn(kc, vc, page_table)
         new_cache = PagedKVCache(kc, vc, t + 1)
     kv_len = jnp.minimum(t + 1, max_pages * page)  # (B,)
     out = _softmax_attn(

@@ -419,6 +419,12 @@ def _capability(mixer: Mixer, cap: str, cfg: ModelConfig, platform: str,
     return getattr(mixer, cap)(cfg)
 
 
+def plan_platform(plan) -> str:
+    """The platform a plan's ops run on: the plan's pin, else JAX's default
+    backend, read when called (at trace time, never at import)."""
+    return getattr(plan, "platform", None) or jax.default_backend()
+
+
 def resolve_mixer(kind: str, cfg: ModelConfig, plan=None) -> BoundMixer:
     """Bind one mixer kind to (cfg, plan), enforcing the plan's demands.
 
@@ -430,8 +436,7 @@ def resolve_mixer(kind: str, cfg: ModelConfig, plan=None) -> BoundMixer:
     ``paged_capable: constant-size decode state (nothing to page)``.
     """
     mixer = get_mixer(kind)
-    platform = ((plan.platform if plan is not None else None)
-                or jax.default_backend())
+    platform = plan_platform(plan)
     rejections = []
     for cap, demand in _plan_demands(plan):
         ok, why = _capability(mixer, cap, cfg, platform,

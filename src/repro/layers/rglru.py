@@ -119,24 +119,28 @@ def _rglru_state_init(cfg: ModelConfig, batch: int) -> RGLRUState:
     )
 
 
-def _boundary_conv_history(xb: Array, lengths: Array, k: int) -> Array:
+def _boundary_conv_history(xb: Array, lengths: Array, k: int,
+                           plan=None) -> Array:
     """Per-row trailing conv inputs AT each row's boundary.
 
     xb: (B, N, W); lengths (B,).  Row i's decode conv history is its last
     ``k-1`` inputs *before* position ``lengths[i]`` — zero-filled on the
     left for rows shorter than the window, exactly like a fresh
-    ``_causal_conv`` pad.  On TPU this is a Pallas per-tap gather reading
-    the raw stream once (no padded-stream materialization); off-TPU it
-    stays the XLA pad + ``take_along_axis``.
+    ``_causal_conv`` pad.  When the plan runs on TPU this is a Pallas
+    per-tap gather reading the raw stream once (no padded-stream
+    materialization); on any other platform it is the XLA pad +
+    ``take_along_axis``.
     """
-    # flowlint: disable=FL001 -- utility gather below the registry; self-falls-back off-TPU
-    from repro.kernels.gather import boundary_gather
+    # flowlint: disable=FL001 -- utility gather below the registry; the plan's platform picks it
+    from repro.kernels.gather import boundary_gather, boundary_gather_xla
 
-    return boundary_gather(xb, lengths, k)
+    if mixer_lib.plan_platform(plan) == "tpu":
+        return boundary_gather(xb, lengths, k)
+    return boundary_gather_xla(xb, lengths, k)
 
 
 def _rglru_prefill(params, x: Array, cfg: ModelConfig,
-                   lengths: Array | None = None):
+                   lengths: Array | None = None, *, plan=None):
     """Prompt prefill; ``lengths`` (B,) packs right-padded prompts into the
     SAME associative scan: gates at positions >= lengths[i] are frozen to
     the identity element (a=1, b=0) so the scan carry — and therefore
@@ -162,7 +166,8 @@ def _rglru_prefill(params, x: Array, cfg: ModelConfig,
     _, h = jax.lax.associative_scan(combine, (a, b), axis=1)
     out = dense(params["w_out"], h.astype(x.dtype) * gb)
     if lengths is not None:
-        hist = _boundary_conv_history(xb, lengths, cfg.rglru.conv_width)
+        hist = _boundary_conv_history(xb, lengths, cfg.rglru.conv_width,
+                                      plan)
     return out, RGLRUState(h=h[:, -1], conv=hist.astype(jnp.bfloat16))
 
 
@@ -215,7 +220,7 @@ class RGLRUMixer(mixer_lib.Mixer):
 
     def prefill_packed(self, params, x, cfg, max_len, lengths, *,
                        positions=None, plan=None):
-        return _rglru_prefill(params, x, cfg, lengths=lengths)
+        return _rglru_prefill(params, x, cfg, lengths=lengths, plan=plan)
 
     def decode_step(self, params, x, state, cfg, *, positions=None,
                     page_table=None, plan=None):

@@ -28,7 +28,7 @@ from repro.layers import mixer as mixer_lib
 from repro.layers.linear import dense, dense_init
 from repro.layers.norms import apply_norm, norm_init
 from repro.layers.rglru import _boundary_conv_history, _causal_conv
-from repro.utils import KeySeq, lecun_normal
+from repro.utils import KeySeq, lecun_normal, varying_zeros
 
 Array = jax.Array
 
@@ -138,9 +138,7 @@ def _ssd_scan_chunked(xh, dt, bmat, cmat, a, chunk: int):
         )
         return h_new, y_intra + y_inter
 
-    h0 = jnp.einsum(  # zero-length contraction: inherits varying axes
-        "bjhp,bjs->bhps", xr[:, 0, :0].astype(jnp.float32), br[:, 0, :0]
-    )
+    h0 = varying_zeros((bsz, h, p, sdim), xr, dtr, br)
     xs = (jnp.moveaxis(xr, 1, 0), jnp.moveaxis(dtr, 1, 0),
           jnp.moveaxis(br, 1, 0), jnp.moveaxis(cr, 1, 0))
     h_final, ys = jax.lax.scan(step, h0, xs)
@@ -148,14 +146,14 @@ def _ssd_scan_chunked(xh, dt, bmat, cmat, a, chunk: int):
     return y, h_final
 
 
-def ssd_block(params, x: Array, cfg: ModelConfig) -> Array:
+def ssd_block(params, x: Array, cfg: ModelConfig, plan=None) -> Array:
     """Full-sequence Mamba-2 block.  x: (B, N, d_model)."""
-    out, _ = _ssd_forward(params, x, cfg, state=None)
+    out, _ = _ssd_forward(params, x, cfg, state=None, plan=plan)
     return out
 
 
 def _ssd_forward(params, x: Array, cfg: ModelConfig, state: SSDState | None,
-                 lengths: Array | None = None):
+                 lengths: Array | None = None, plan=None):
     """``lengths`` (B,) packs right-padded prompts into ONE chunked scan:
     dt at positions >= lengths[i] is zeroed, so the decay exp(dt*a) is 1
     and the input term dt*x is 0 — the scan-carried state freezes at each
@@ -178,12 +176,13 @@ def _ssd_forward(params, x: Array, cfg: ModelConfig, state: SSDState | None,
                 < lengths.astype(jnp.int32)[:, None])  # (B,N)
         dt = dt * live[..., None]
         new_hist = tuple(
-            _boundary_conv_history(r, lengths, s.conv_width) for r in raw
+            _boundary_conv_history(r, lengths, s.conv_width, plan)
+            for r in raw
         )
     a = -jnp.exp(params["a_log"])  # (H,)
 
     h0 = None if state is None else state.h
-    if state is None and jax.default_backend() == "tpu":
+    if state is None and mixer_lib.plan_platform(plan) == "tpu":
         # training path on TPU: fused Pallas chunk kernel (state discarded)
         # flowlint: disable=FL001 -- the ssd mixer IS this kernel's provider (no registry tier between)
         from repro.kernels.ssd_chunk import ssd_scan_pallas
@@ -232,9 +231,9 @@ def _ssd_state_init(cfg: ModelConfig, batch: int) -> SSDState:
 
 
 def _ssd_prefill(params, x: Array, cfg: ModelConfig,
-                 lengths: Array | None = None):
+                 lengths: Array | None = None, *, plan=None):
     state = _ssd_state_init(cfg, x.shape[0])
-    return _ssd_forward(params, x, cfg, state, lengths=lengths)
+    return _ssd_forward(params, x, cfg, state, lengths=lengths, plan=plan)
 
 
 def _ssd_decode(params, x: Array, state: SSDState, cfg: ModelConfig):
@@ -300,7 +299,7 @@ class SSDMixer(mixer_lib.Mixer):
         return ssd_init(key, cfg)
 
     def forward(self, params, x, cfg, *, positions=None, plan=None):
-        return ssd_block(params, x, cfg)
+        return ssd_block(params, x, cfg, plan)
 
     def state_init(self, cfg, batch, max_len, *, dtype=None, plan=None):
         from repro.serving.quant import maybe_quantize
@@ -312,7 +311,7 @@ class SSDMixer(mixer_lib.Mixer):
 
     def prefill_packed(self, params, x, cfg, max_len, lengths, *,
                        positions=None, plan=None):
-        return _ssd_prefill(params, x, cfg, lengths=lengths)
+        return _ssd_prefill(params, x, cfg, lengths=lengths, plan=plan)
 
     def decode_step(self, params, x, state, cfg, *, positions=None,
                     page_table=None, plan=None):

@@ -16,11 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax moved shard_map out of experimental in 0.5; support both
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PyTree = Any
 
 
@@ -70,7 +65,7 @@ def make_compressed_allreduce(mesh, dp_axis: str = "data"):
     def fn(grads, residual):
         return compressed_psum(grads, residual, dp_axis)
 
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), P()),  # grads replicated per-DP-shard semantics
         out_specs=(P(), P()),

@@ -56,6 +56,22 @@ def scaled_init(key, shape, scale: float, fan_in: int, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 # Pytree helpers
 # ---------------------------------------------------------------------------
+def varying_zeros(shape, *like: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """Zeros of ``shape`` varying over the ``shard_map`` manual axes that
+    any of ``like`` varies over (a scan carry's type must match the
+    updates it absorbs); plain zeros outside ``shard_map``."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    z = jnp.zeros(shape, dtype)
+    return jax.lax.pcast(z, tuple(sorted(vma)), to="varying") if vma else z
+
+
+def device_summary() -> str:
+    """The devices JAX runs on, as the banner lines print them."""
+    devs = jax.devices()
+    return (f"platform={devs[0].platform} device_kind={devs[0].device_kind}"
+            f" devices={len(devs)}")
+
+
 def tree_size(tree: PyTree) -> int:
     """Total number of scalar parameters in a pytree."""
     return sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))

@@ -21,6 +21,7 @@ from repro import attention
 from repro.attention import ExecutionPlan, FlowConfig, ShapeInfo, ShardSpec
 
 from conftest import assert_close
+from repro.launch.mesh import make_mesh
 from test_sharding import run_with_devices
 
 
@@ -31,11 +32,12 @@ def test_cp_backends_match_unsharded_oracle():
     code = textwrap.dedent("""
         import dataclasses, json
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro import attention
         from repro.attention import (ExecutionPlan, FlowConfig, ShapeInfo,
                                      ShardSpec)
 
-        mesh = jax.make_mesh((8,), ("seq",))
+        mesh = make_mesh((8,), ("seq",))
         B, H, Hkv, N, D = 2, 4, 2, 128, 16
         q = jax.random.normal(jax.random.PRNGKey(0), (B, H, N, D))
         k = jax.random.normal(jax.random.PRNGKey(1), (B, Hkv, N, D))
@@ -130,11 +132,12 @@ def test_cp_inner_strategy_is_resolvable_and_pinnable():
     code = textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro import attention
         from repro.attention import (ExecutionPlan, FlowConfig, ShapeInfo,
                                      ShardSpec)
 
-        mesh = jax.make_mesh((8,), ("seq",))
+        mesh = make_mesh((8,), ("seq",))
         B, H, N, D = 1, 2, 128, 8
         q = jax.random.normal(jax.random.PRNGKey(0), (B, H, N, D))
         k = jax.random.normal(jax.random.PRNGKey(1), (B, H, N, D))
@@ -181,7 +184,7 @@ def _qkv(key, b, hq, hkv, n, d):
 def test_sharded_rejections_name_missing_glue():
     """Every single-device backend refuses a sharded plan with a "no
     collective glue" reason carried in ResolutionError.rejections."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FlowConfig(causal=True, strict_causal=True, chunk_size=16)
     shapes = ShapeInfo(b=1, hq=2, hkv=2, n=64, m=64, d=8, dv=8)
     with pytest.raises(attention.ResolutionError) as ei:

@@ -224,7 +224,9 @@ def test_flow_decode_q_step_matches_dequantized_oracle():
 
 
 def test_paged_gather_quant_interpret_matches_xla():
-    from repro.kernels.gather import paged_gather, paged_gather_quant
+    import functools
+
+    from repro.kernels import gather
 
     p, hkv, page, d = 6, 2, 8, 16
     kc = jax.random.normal(jax.random.PRNGKey(0), (p, hkv, page, d))
@@ -233,16 +235,19 @@ def test_paged_gather_quant_interpret_matches_xla():
     vq, vs = quantize_leaf(vc, spec_of("int8"), "token")
     table = jnp.array([[0, 3, 6], [5, 1, 6]], jnp.int32)  # 6 == sentinel
 
-    for interpret in (None, True):  # XLA fallback AND the Pallas kernel
-        kg, vg = paged_gather_quant(kq, vq, ks, vs, table,
-                                    out_dtype=jnp.float32,
-                                    interpret=interpret)
+    kernel = functools.partial(gather.paged_gather_quant, interpret=True)
+    kernel_fp = functools.partial(gather.paged_gather, interpret=True)
+    # the XLA gathers AND the Pallas kernels
+    for gather_q, gather_fp in ((gather.paged_gather_quant_xla,
+                                 gather.paged_gather_xla),
+                                (kernel, kernel_fp)):
+        kg, vg = gather_q(kq, vq, ks, vs, table, out_dtype=jnp.float32)
         assert kg.shape == (2, hkv, 3 * page, d)
         # dequantized gather == full-precision gather of the dequantized
         # pool (same clamped page semantics)
         kd = kq.astype(jnp.float32) * ks
         vd = vq.astype(jnp.float32) * vs
-        rk, rv = paged_gather(kd, vd, table, interpret=interpret)
+        rk, rv = gather_fp(kd, vd, table)
         np.testing.assert_allclose(np.asarray(kg), np.asarray(rk), atol=1e-6)
         np.testing.assert_allclose(np.asarray(vg), np.asarray(rv), atol=1e-6)
 
