@@ -68,6 +68,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.config import ModelConfig
 
 Array = jax.Array
@@ -329,8 +330,9 @@ class BoundMixer:
 
     def forward(self, params, x: Array, *,
                 positions: Array | None = None) -> Array:
-        return self.mixer.forward(params, x, self.cfg, positions=positions,
-                                  plan=self.plan)
+        with jax.named_scope(scopes.MIXER):
+            return self.mixer.forward(params, x, self.cfg,
+                                      positions=positions, plan=self.plan)
 
     def state_init(self, batch: int, max_len: int, dtype=None):
         return self.mixer.state_init(self.cfg, batch, max_len, dtype=dtype,
@@ -343,8 +345,9 @@ class BoundMixer:
         without the capability raises the same rejection ``resolve_mixer``
         would (there is no NotImplementedError path)."""
         if lengths is None:
-            return self.mixer.prefill(params, x, self.cfg, max_len,
-                                      positions=positions, plan=self.plan)
+            with jax.named_scope(scopes.MIXER):
+                return self.mixer.prefill(params, x, self.cfg, max_len,
+                                          positions=positions, plan=self.plan)
         ok, why = self.mixer.packable(self.cfg)
         if not ok:
             raise MixerResolutionError(
@@ -352,16 +355,19 @@ class BoundMixer:
                 f"missing capability packable: {why}",
                 ((self.kind, "packable", why),),
             )
-        return self.mixer.prefill_packed(params, x, self.cfg, max_len,
-                                         lengths, positions=positions,
-                                         plan=self.plan)
+        with jax.named_scope(scopes.MIXER):
+            return self.mixer.prefill_packed(params, x, self.cfg, max_len,
+                                             lengths, positions=positions,
+                                             plan=self.plan)
 
     def decode_step(self, params, x: Array, state, *,
                     positions: Array | None = None,
                     page_table: Array | None = None):
-        return self.mixer.decode_step(params, x, state, self.cfg,
-                                      positions=positions,
-                                      page_table=page_table, plan=self.plan)
+        with jax.named_scope(scopes.MIXER):
+            return self.mixer.decode_step(params, x, state, self.cfg,
+                                          positions=positions,
+                                          page_table=page_table,
+                                          plan=self.plan)
 
     def verify_step(self, params, x: Array, state, *,
                     positions: Array | None = None,

@@ -24,6 +24,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.config import ModelConfig
 from repro.layers.embeddings import embed, embedding_init, unembed
 from repro.layers.ffn import ffn, ffn_init
@@ -65,14 +66,18 @@ def _mixer(params, x, kind: str, cfg: ModelConfig, positions, plan=None):
 def _block_apply(params, x, kind: str, cfg: ModelConfig, positions, plan=None):
     from repro.distribution.act_sharding import constrain_residual
 
-    h = apply_norm(params["norm1"], x, cfg.norm)
+    with jax.named_scope(scopes.NORM):
+        h = apply_norm(params["norm1"], x, cfg.norm)
     x = constrain_residual(x + _mixer(params, h, kind, cfg, positions, plan))
     aux = jnp.zeros((), jnp.float32)
-    if "ffn" in params:
-        x = x + ffn(params["ffn"], apply_norm(params["norm2"], x, cfg.norm), cfg.act)
-    elif "moe" in params:
-        y, aux = moe(params["moe"], apply_norm(params["norm2"], x, cfg.norm),
-                     cfg.act, cfg.moe)
+    if "ffn" in params or "moe" in params:
+        with jax.named_scope(scopes.NORM):
+            h = apply_norm(params["norm2"], x, cfg.norm)
+        with jax.named_scope(scopes.FFN):
+            if "ffn" in params:
+                y = ffn(params["ffn"], h, cfg.act)
+            else:
+                y, aux = moe(params["moe"], h, cfg.act, cfg.moe)
         x = x + y
     return constrain_residual(x), aux
 
@@ -135,7 +140,8 @@ def forward(
     Returns (logits (B, N, vocab) fp32, aux_loss scalar)."""
     b = inputs.shape[0]
     n = inputs.shape[1]
-    x = _embed_inputs(params, inputs, cfg, dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = _embed_inputs(params, inputs, cfg, dtype)
     if positions is None:
         positions = (
             default_mrope_positions(b, n) if cfg.rope == "mrope"
@@ -181,9 +187,11 @@ def forward(
             x, a = f(bp, x)
             aux_total = aux_total + a
 
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    with jax.named_scope(scopes.NORM):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = unembed(head, x, softcap=cfg.logit_softcap)
+    with jax.named_scope(scopes.HEAD):
+        logits = unembed(head, x, softcap=cfg.logit_softcap)
     return logits, aux_total
 
 
@@ -196,8 +204,9 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, *, dtype=jnp.bfloat16,
     mask = batch.get("mask")
     if mask is None:
         mask = jnp.ones_like(targets, jnp.float32)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope(scopes.HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     denom = jnp.maximum(mask.sum(), 1.0)
     ce = (nll * mask).sum() / denom
     loss = ce + aux

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import scopes
 from repro.distribution.sharding import (
     batch_spec,
     to_shardings,
@@ -136,16 +137,17 @@ def make_train_step(
             grads = grad_fn(params, batch)
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
 
-        lr = _lr(state.step, tcfg)
-        if tcfg.optimizer == "adamw":
-            new_master, new_opt, stats = opt_lib.adamw_update(
-                grads, state.opt, state.master, lr, adamw_cfg
-            )
-        else:
-            new_master, new_opt, stats = opt_lib.adafactor_update(
-                grads, state.opt, state.master, lr,
-                opt_lib.AdafactorConfig(weight_decay=tcfg.weight_decay),
-            )
+        with jax.named_scope(scopes.OPTIMIZER):
+            lr = _lr(state.step, tcfg)
+            if tcfg.optimizer == "adamw":
+                new_master, new_opt, stats = opt_lib.adamw_update(
+                    grads, state.opt, state.master, lr, adamw_cfg
+                )
+            else:
+                new_master, new_opt, stats = opt_lib.adafactor_update(
+                    grads, state.opt, state.master, lr,
+                    opt_lib.AdafactorConfig(weight_decay=tcfg.weight_decay),
+                )
         metrics = {**metrics, **stats, "lr": lr}
         return TrainState(new_master, new_opt, state.step + 1), metrics
 
