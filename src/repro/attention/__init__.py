@@ -81,9 +81,10 @@ Registered strategies
 * ``pallas_chunk``  — causal aggregation in a Pallas TPU kernel with the
   (D, Dv) carry in VMEM scratch (``kernels/flow_chunk``).
 * ``fused_causal``  — strict-causal flows + cumulative softmax +
-  aggregation in ONE chunked ``lax.scan``; the carry is the decode
-  ``FlowState``, so prefill returns the serving hand-off for free and no
-  (B, H, N) intermediate round-trips HBM (see ``attention/fused.py``).
+  aggregation, chunk-parallel: chunk-local prefixes, an exclusive prefix
+  over the chunk totals, batched contractions, no loop; the totals are the
+  decode ``FlowState``, so prefill returns the serving hand-off for free
+  (see ``attention/fused.py``).
 * ``xla_chunked``   — unfused normalizers + chunked-scan aggregation
   (absorbed from the former ``core/chunked.py``).
 * ``xla_cumsum``    — unfused normalizers + full-length cumsum aggregation;

@@ -12,10 +12,11 @@ their position in the order never affects unsharded plans).
 
 Pallas backends only self-report applicable on TPU (interpret mode must be
 asked for explicitly, and their ops then take ``interpret=True`` from
-``registry.run_kwargs``); ``fused_causal`` carries the competition normalizer
-and the (D, Dv) aggregation state through one scan and is preferred over the
-multi-pass XLA paths wherever its contract (strict causal competition,
-chunkable length) holds; ``xla_cumsum`` accepts everything and is the
+``registry.run_kwargs``); ``fused_causal`` computes the flow normalizers, the
+competition normalizer and the (D, Dv) aggregation state for all chunks at
+once (a chunk-local prefix, then an exclusive prefix over the chunk totals,
+then batched contractions) and is preferred over the multi-pass XLA paths
+wherever its contract (strict causal competition, chunkable length) holds; ``xla_cumsum`` accepts everything and is the
 correctness anchor; ``pallas_decode`` runs the serving hot loop (one grid
 launch over the whole slot pool) ahead of ``recurrent``, which stays the
 decode fallback and a token-by-token oracle.  The pipeline-based causal
@@ -290,9 +291,11 @@ class PallasFused(_ChunkedVerifyQuant, Backend):
 
 
 class FusedCausal(Backend):
-    """Strict-causal flows + cumulative softmax + aggregation in ONE scan —
-    the O(d^2) FlowState is the carry, so prefill hands decode its state for
-    free and no (B,H,N) intermediate ever round-trips HBM."""
+    """Strict-causal flows + cumulative softmax + aggregation, chunk-parallel:
+    every running sum is a chunk-local prefix plus an exclusive prefix over
+    the chunk totals, and the aggregation is batched over all chunks, so no
+    loop runs chunk after chunk.  The totals are the O(d^2) FlowState, so
+    prefill hands decode its state for free."""
 
     provides = frozenset({"forward", "prefill", "prefill_packed"})
     differentiable = frozenset({"forward", "prefill", "prefill_packed"})
@@ -307,7 +310,7 @@ class FusedCausal(Backend):
             return False, "fused carry includes the competition normalizer"
         if not cfg.chunk_size or cfg.chunk_size <= 0:
             return False, "chunk_size <= 0"
-        return True, "fused strict-causal scan"
+        return True, "chunk-parallel strict-causal flow"
 
     def forward(self, q, k, v, cfg):
         k, v = pipeline.expand_kv(q, k, v, cfg)

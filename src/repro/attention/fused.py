@@ -1,29 +1,26 @@
-"""Fused strict-causal Flow-Attention: one scan, no (B,H,N) HBM rounds.
+"""Fused strict-causal Flow-Attention, chunk-parallel: no sequential loop.
 
-The unfused strict-causal pipeline materializes the full-length flow
-normalizers (``sink_in``/``src_out``/``cons_*``), the competition weights
-``e = exp(cons_src)`` and the cumulative normalizer ``z`` as (B, H, N[, D])
-HBM tensors across several ``cumsum`` passes, and only then runs a separate
-chunked causal dot over the weighted values.  Each pass re-streams
-O(B*H*N*D) bytes through HBM.
+Paper Alg. 2 (strict-causal variant) needs five running sums along the
+sequence — the flow normalizers ``k_sum``/``q_sum``/``ko_sum``/``qi_sum``
+and the cumulative competition ``z`` — plus the running (D, Dv) state
+``S = sum_j K_j^T V_w,j``.  Every one of them is a prefix sum, and a prefix
+sum splits over chunks of C tokens into a chunk-local prefix plus an
+exclusive prefix over the chunk totals.  So the sequence is reshaped to
+(nc, C) and the chunk axis stays a batch axis of every op:
 
-This module fuses the whole of paper Alg. 2 (strict-causal variant) into a
-single ``lax.scan`` over sequence chunks.  The carry is exactly the O(d^2)
-``FlowState`` — the same state recurrent decode consumes — and every
-intermediate inside a scan step is chunk-sized:
-
-    per chunk c (size C):
-      k/q running sums -> sink_in, src_out          (C-local cumsums + carry)
+    for all chunks at once (chunk-local prefix + exclusive chunk prefix):
+      k/q running sums -> sink_in, src_out
       ko/qi running sums -> cons_sink, cons_src     (conservation, Eq. 7)
-      e = exp(clip(cons_src)); z += cumsum(e)       (cumulative competition)
+      e = exp(clip(cons_src)); z = running sum of e (cumulative competition)
       v_w = V * e
-      out_c = [tril(Q'_c K_c^T) v_w + Q'_c S] * (pos/z) * alloc
-      S += K_c^T v_w                                (carried (D, Dv) state)
+      S_c = exclusive prefix over chunks of K_c^T v_w,c    (B,H,nc,D,Dv)
+      out_c = [tril(Q'_c K_c^T) v_w,c + Q'_c S_c] * (pos/z) * alloc
 
-All heavy ops are (C,C)x(C,Dv) and (C,D)x(D,Dv) matmuls (MXU-friendly,
-128-alignable); HBM traffic is one read of q/k/v and one write of out.
-Because the final carry IS the decode ``FlowState``, prefill gets the
-serving hand-off for free.
+The heavy ops are batched (C,C)x(C,Dv) and (C,D)x(D,Dv) matmuls over all
+chunks; nothing runs chunk after chunk, forward or backward.  The returned
+state is the totals — the last position's sums and the sum of the chunk
+states — which is exactly the decode ``FlowState``, so prefill hands serving
+its state for free.
 """
 from __future__ import annotations
 
@@ -57,6 +54,35 @@ def _pad_seq(x: Array, n_pad: int, axis: int) -> Array:
     return jnp.pad(x, pad)
 
 
+def _local_prefix(x: Array, axis: int) -> Array:
+    """Inclusive running sum along ``axis`` (a chunk's tokens), as a
+    lower-triangular matmul on the MXU.  ``Precision.HIGHEST`` keeps it the
+    fp32 sum a cumsum gives: at the default precision the TPU would round
+    ``x`` to bf16 first."""
+    tri = jnp.tri(x.shape[axis], dtype=jnp.float32)
+    y = jnp.einsum("...j,ij->...i", jnp.moveaxis(x, axis, -1), tri,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    return jnp.moveaxis(y, -1, axis)
+
+
+def _exclusive(x: Array, axis: int) -> Array:
+    """Sum of the entries before each one along ``axis`` (the chunks)."""
+    x = jnp.cumsum(x, axis=axis)
+    zero = jnp.zeros_like(jax.lax.index_in_dim(x, 0, axis))
+    return jnp.concatenate([zero, jax.lax.slice_in_dim(x, 0, -1, axis=axis)],
+                           axis=axis)
+
+
+def _prefix(x: Array, axis: int) -> Array:
+    """Inclusive running sum over the flattened (chunk ``axis``, token
+    ``axis + 1``) pair: a chunk-local prefix plus the exclusive prefix of
+    the chunk totals."""
+    local = _local_prefix(x, axis + 1)
+    totals = jax.lax.index_in_dim(local, -1, axis + 1, keepdims=True)
+    return local + _exclusive(totals, axis)
+
+
 def fused_causal_forward(
     q: Array,
     k: Array,
@@ -66,18 +92,18 @@ def fused_causal_forward(
     return_state: bool = False,
     lengths: Array | None = None,
 ):
-    """Strict-causal Flow-Attention in one fused chunked scan.
+    """Strict-causal Flow-Attention, all chunks at once.
 
     q: (B, Hq, N, D); k: (B, Hkv, N, D); v: (B, Hkv, N, Dv); N == M.
     Requires ``strict_causal`` and ``use_competition`` (the cumulative
-    softmax is what admits the O(d^2) carry).  GQA-expand must be applied by
+    softmax is what admits the O(d^2) state).  GQA-expand must be applied by
     the caller (see ``pipeline.expand_kv``); this function implements shared
     semantics over whatever kv heads it is given.
 
     ``lengths`` (B,) selects packed-prefill semantics: positions past each
     row's length contribute zero phi/e, so every running sum freezes at the
-    boundary and the final carry is that row's boundary ``FlowState`` — the
-    same masking that makes non-chunk-multiple N a pad-and-mask, not a
+    boundary and the returned totals are that row's boundary ``FlowState`` —
+    the same masking that makes non-chunk-multiple N a pad-and-mask, not a
     degenerate-chunk, problem.
     """
     assert cfg.strict_causal and cfg.use_competition, (
@@ -109,101 +135,73 @@ def fused_causal_forward(
     phi_k = phi_k * row_ok[:, None, :, None]
     vf = _pad_seq(v, n_pad, 2).astype(jnp.float32)
 
-    qg = _group(phi_q, hkv)  # (B,Hkv,G,n_pad,D)
-    g = qg.shape[2]
+    # chunk the sequence axis in place: every op batches over the chunks
+    qs = _group(phi_q, hkv).reshape(b, hkv, -1, nc, c, d)  # (B,H,G,nc,c,d)
+    g = qs.shape[2]
+    ks = phi_k.reshape(b, hkv, nc, c, d)
+    vs = vf.reshape(b, hkv, nc, c, dv)
+    # 1-based global positions: sources seen up to each position, and the
+    # sinks seen (G per position)
+    normal_k = (jnp.arange(n_pad, dtype=jnp.float32) + 1.0).reshape(nc, c)
+    normal_q = normal_k * g
+    ok = row_ok.reshape(b, 1, nc, c)
 
-    # chunk the sequence axis and lead with it for the scan
-    qs = jnp.moveaxis(qg.reshape(b, hkv, g, nc, c, d), 3, 0)  # (nc,B,H,G,c,d)
-    ks = jnp.moveaxis(phi_k.reshape(b, hkv, nc, c, d), 2, 0)  # (nc,B,H,c,d)
-    vs = jnp.moveaxis(vf.reshape(b, hkv, nc, c, dv), 2, 0)  # (nc,B,H,c,dv)
-    # 1-based global positions per chunk: (nc, c)
-    pos = (jnp.arange(n_pad, dtype=jnp.float32) + 1.0).reshape(nc, c)
-    oks = jnp.moveaxis(row_ok.reshape(b, nc, c), 1, 0)  # (nc, B, c)
+    # (1) flows from the running source/sink sums
+    k_csum = _prefix(ks, 2)  # (B,H,nc,c,d)
+    q_csum = _prefix(qs.sum(axis=2), 2)
+    sink_in = normal_k / jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
+                                    k_csum + eps)
+    src_out = normal_q / jnp.einsum("bhncd,bhncd->bhnc", ks + eps,
+                                    q_csum + eps)
 
-    mask = jnp.tril(jnp.ones((c, c), jnp.float32))
-    f32 = jnp.float32
-    carry0 = FlowState(
-        t=t,  # static; only sums/z/s evolve
-        q_sum=jnp.zeros((b, hkv, d), f32),
-        k_sum=jnp.zeros((b, hkv, d), f32),
-        ko_sum=jnp.zeros((b, hkv, d), f32),
-        qi_sum=jnp.zeros((b, hkv, d), f32),
-        z=jnp.zeros((b, hkv), f32),
-        s=jnp.zeros((b, hkv, d, dv), f32),
+    # (2) conservation refinement
+    ko_csum = _prefix(ks * src_out[..., None], 2)
+    cons_sink = jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
+                           ko_csum + eps) / normal_q
+    qi_csum = _prefix((qs * sink_in[..., None]).sum(axis=2), 2)
+    cons_src = jnp.clip(
+        jnp.einsum("bhncd,bhncd->bhnc", ks + eps, qi_csum + eps) / normal_k,
+        -1.0, 1.0,
     )
 
-    def step(st: FlowState, xs):
-        qc, kc, vc, p, ok = xs  # (B,H,G,c,d), (B,H,c,d), (B,H,c,dv), (c,), (B,c)
-        normal_k = p  # sources seen up to position i
-        normal_q = p * g  # sinks seen (G per position)
+    # (3) cumulative competition + allocation
+    if cfg.use_allocation:
+        alloc = jax.nn.sigmoid(cons_sink)
+    else:
+        alloc = jnp.ones_like(cons_sink)
+    # e masked past each row's boundary so z freezes with the sums
+    e = jnp.exp(cons_src) * ok  # in [1/e, e] while valid
+    z = _prefix(e, 2)  # (B,H,nc,c)
+    v_w = vs * e[..., None]
 
-        # (1) flows from carried sums + chunk-local inclusive cumsums
-        k_csum = st.k_sum[:, :, None] + jnp.cumsum(kc, axis=2)  # (B,H,c,d)
-        q_csum = st.q_sum[:, :, None] + jnp.cumsum(qc.sum(axis=2), axis=2)
-        sink_in = normal_k / jnp.einsum(
-            "bhgnd,bhnd->bhgn", qc + eps, k_csum + eps
-        )
-        src_out = normal_q / jnp.einsum(
-            "bhnd,bhnd->bhn", kc + eps, q_csum + eps
-        )
-
-        # (2) conservation refinement
-        ko_csum = st.ko_sum[:, :, None] + jnp.cumsum(
-            kc * src_out[..., None], axis=2
-        )
-        cons_sink = jnp.einsum(
-            "bhgnd,bhnd->bhgn", qc + eps, ko_csum + eps
-        ) / normal_q
-        qi_csum = st.qi_sum[:, :, None] + jnp.cumsum(
-            (qc * sink_in[..., None]).sum(axis=2), axis=2
-        )
-        cons_src = jnp.clip(
-            jnp.einsum("bhnd,bhnd->bhn", kc + eps, qi_csum + eps) / normal_k,
-            -1.0,
-            1.0,
-        )
-
-        # (3) cumulative competition + allocation
-        if cfg.use_allocation:
-            alloc = jax.nn.sigmoid(cons_sink)
-        else:
-            alloc = jnp.ones_like(cons_sink)
-        # e masked past each row's boundary so z freezes with the sums
-        e = jnp.exp(cons_src) * ok[:, None, :]  # in [1/e, e] while valid
-        z = st.z[:, :, None] + jnp.cumsum(e, axis=2)  # (B,H,c)
-        v_w = vc * e[..., None]
-
-        # (4) aggregation: intra-chunk tril matmul + carried (D,Dv) state
-        q_in = qc * sink_in[..., None]
-        scores = jnp.einsum(
-            "bhgid,bhjd->bhgij", q_in, kc, preferred_element_type=jnp.float32
-        )
-        intra = jnp.einsum(
-            "bhgij,bhje->bhgie", scores * mask, v_w,
-            preferred_element_type=jnp.float32,
-        )
-        inter = jnp.einsum(
-            "bhgid,bhde->bhgie", q_in, st.s, preferred_element_type=jnp.float32
-        )
-        out = (intra + inter) * (normal_k / z)[:, :, None, :, None]
-        out = out * alloc[..., None]
-
-        new = FlowState(
-            t=st.t,
-            q_sum=q_csum[:, :, -1],
-            k_sum=k_csum[:, :, -1],
-            ko_sum=ko_csum[:, :, -1],
-            qi_sum=qi_csum[:, :, -1],
-            z=z[:, :, -1],
-            s=st.s + jnp.einsum(
-                "bhjd,bhje->bhde", kc, v_w, preferred_element_type=jnp.float32
-            ),
-        )
-        return new, out.astype(out_dtype)
-
-    state, outs = jax.lax.scan(step, carry0, (qs, ks, vs, pos, oks))
-    out = _ungroup(jnp.moveaxis(outs, 0, 3).reshape(b, hkv, g, n_pad, dv))
-    out = out[:, :, :n]
-    if return_state:
-        return out, state
-    return out
+    # (4) aggregation: intra-chunk tril matmul + the (D,Dv) state of every
+    # earlier chunk
+    f32 = jnp.float32
+    q_in = qs * sink_in[..., None]
+    scores = jnp.einsum("bhgnid,bhnjd->bhgnij", q_in, ks,
+                        preferred_element_type=f32)
+    mask = jnp.tril(jnp.ones((c, c), f32))
+    intra = jnp.einsum("bhgnij,bhnje->bhgnie", scores * mask, v_w,
+                       preferred_element_type=f32)
+    states = jnp.einsum("bhnjd,bhnje->bhnde", ks, v_w,
+                        preferred_element_type=f32)  # (B,H,nc,D,Dv)
+    s_before = _exclusive(states, 2)
+    inter = jnp.einsum("bhgnid,bhnde->bhgnie", q_in, s_before,
+                       preferred_element_type=f32)
+    out = (intra + inter) * (normal_k / z)[:, :, None, ..., None]
+    out = (out * alloc[..., None]).astype(out_dtype)
+    out = _ungroup(out.reshape(b, hkv, g, n_pad, dv))[:, :, :n]
+    if not return_state:
+        return out
+    # the totals: running sums at the last (padded) position, which the
+    # masking froze at each row's boundary
+    state = FlowState(
+        t=t,
+        q_sum=q_csum[:, :, -1, -1],
+        k_sum=k_csum[:, :, -1, -1],
+        ko_sum=ko_csum[:, :, -1, -1],
+        qi_sum=qi_csum[:, :, -1, -1],
+        z=z[:, :, -1, -1],
+        s=s_before[:, :, -1] + states[:, :, -1],
+    )
+    return out, state

@@ -6,8 +6,8 @@ execution strategies; what differs is how the causal aggregation
 therefore takes the aggregation as a ``dot_fn`` argument — backends inject
 cumsum, chunked-scan or Pallas dots without duplicating the flow math.
 
-The fully fused strict-causal path (normalizers + competition + aggregation
-in one scan, no (B,H,N) HBM intermediates) lives in ``attention/fused.py``.
+The fused strict-causal path (normalizers + competition + aggregation,
+chunk-parallel) lives in ``attention/fused.py``.
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: the WHOLE strict-causal Flow-Attention pipeline.
 
-``attention/fused.py`` fuses paper Alg. 2 into one ``lax.scan`` whose carry
-is the O(d^2) ``FlowState``; this kernel moves that scan onto the Pallas
-grid.  Per (batch*kv_head, chunk) grid step the kernel computes
+This kernel runs paper Alg. 2 (the math of ``attention/fused.py``) as one
+scan over the Pallas grid whose carry is the O(d^2) ``FlowState``.  Per
+(batch*kv_head, chunk) grid step the kernel computes
 
     k/q running sums -> sink_in, src_out          (chunk cumsums + carry)
     ko/qi running sums -> cons_sink, cons_src     (conservation, Eq. 7)

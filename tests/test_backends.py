@@ -79,12 +79,20 @@ def test_expand_equals_shared_at_g1(backend):
     assert_close(a, b, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("backend", ["xla_cumsum", "xla_chunked",
-                                     "fused_causal", "pallas_fused",
-                                     "recurrent"])
-def test_prefill_state_parity(backend):
+def _with_fused_lengths(backends, n, lengths):
+    """Each backend at length ``n``, then ``fused_causal`` again at each of
+    ``lengths`` (under one chunk of 16, no multiple of it)."""
+    return ([pytest.param(b, n, id=b) for b in backends]
+            + [pytest.param("fused_causal", m, id=f"fused_causal-n{m}")
+               for m in lengths])
+
+
+@pytest.mark.parametrize("backend,n", _with_fused_lengths(
+    ["xla_cumsum", "xla_chunked", "fused_causal", "pallas_fused",
+     "recurrent"], 32, [9, 37]))
+def test_prefill_state_parity(backend, n):
     """All prefill-capable backends hand decode the same FlowState."""
-    q, k, v = _qkv(3, 1, 4, 2, 32, 8)
+    q, k, v = _qkv(3, 1, 4, 2, n, 8)
     cfg = FlowConfig(causal=True, strict_causal=True, chunk_size=16,
                      backend=backend)
     if not _applicable(cfg, q, k, v, op="prefill"):
@@ -229,20 +237,20 @@ def test_pallas_decode_matches_recurrent_with_churn(gqa):
 # ---------------------------------------------------------------------------
 # packed prefill (prefill_packed op)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", ["xla_cumsum", "xla_chunked",
-                                     "pallas_chunk", "fused_causal",
-                                     "pallas_fused"])
-def test_prefill_packed_matches_per_row_prefill(backend):
+@pytest.mark.parametrize("backend,n", _with_fused_lengths(
+    ["xla_cumsum", "xla_chunked", "pallas_chunk", "fused_causal",
+     "pallas_fused"], 32, [12, 37]))
+def test_prefill_packed_matches_per_row_prefill(backend, n):
     """A right-padded batch prefilled in one call hands decode the same
     per-row FlowState as prefilling each prompt alone (causality keeps
     padding out of every prefix)."""
-    b, hq, hkv, n, d = 3, 4, 2, 32, 8
+    b, hq, hkv, d = 3, 4, 2, 8
     cfg = FlowConfig(causal=True, strict_causal=True, chunk_size=16,
                      backend=backend)
     q, k, v = _qkv(11, b, hq, hkv, n, d)
     if not _applicable(cfg, q, k, v, op="prefill_packed"):
         pytest.skip(f"{backend} prefill_packed not applicable")
-    lens = [19, 32, 7]
+    lens = [min(19, n), n, 7]
     out_p, st_p = attention.prefill(q, k, v, cfg, lengths=jnp.asarray(lens))
     assert np.asarray(st_p.t).tolist() == lens
     ref_cfg = dataclasses.replace(cfg, backend="xla_cumsum")  # any length

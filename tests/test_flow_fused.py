@@ -1,5 +1,6 @@
 """pallas_fused kernel family (interpret=True): forward/backward parity vs
-the one-scan XLA pipeline, packed boundary states, padding, resolution."""
+the chunk-parallel XLA pipeline, packed boundary states, padding,
+resolution."""
 import dataclasses
 
 import jax
@@ -69,7 +70,7 @@ def test_flow_fused_phi_kinds(phi):
 
 
 def test_flow_fused_ref_matches_fused_causal():
-    """The oracle itself reproduces the production one-scan pipeline,
+    """The oracle itself reproduces the production chunk-parallel pipeline,
     state included (shared-GQA semantics)."""
     b, hq, hkv, n, d = 2, 4, 2, 64, 16
     q, k, v = _qkv(3, b, hq, hkv, n, d)
@@ -155,6 +156,30 @@ def test_flow_fused_forward_odd_n_grads():
     assert_close(out_a, out_b, rtol=1e-3, atol=1e-4)
     for a, b_, name in zip(ga, gb, ["dq", "dk", "dv"]):
         assert_close(a, b_, rtol=3e-3, atol=1e-3, msg=name)
+
+
+def test_fused_causal_lowers_to_no_loop():
+    """Chunk-parallel: neither the forward, the prefill (state returned) nor
+    their gradient lowers to a loop over the chunks."""
+    q, k, v = _qkv(31, 2, 4, 2, 100, 8)
+    cfg = FlowConfig(causal=True, strict_causal=True, chunk_size=16)
+    lens = jnp.array([100, 41])
+
+    def fwd(q_, k_, v_):
+        return fused_causal_forward(q_, k_, v_, cfg)
+
+    def prefill(q_, k_, v_):
+        return fused_causal_forward(q_, k_, v_, cfg, return_state=True,
+                                    lengths=lens)
+
+    def loss(q_, k_, v_):
+        out, st = prefill(q_, k_, v_)
+        return jnp.sum(out ** 2) + sum(jnp.sum(x) for x in st[1:])
+
+    for name, f in [("forward", fwd), ("prefill", prefill),
+                    ("grad", jax.grad(loss, (0, 1, 2)))]:
+        text = jax.jit(f).lower(q, k, v).as_text()
+        assert "while" not in text, f"{name} lowers to a loop"
 
 
 def test_flow_fused_packed_prefill_to_decode_handoff():

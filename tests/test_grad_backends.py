@@ -36,15 +36,18 @@ def _applicable(cfg, q, k, v, op="forward"):
     return ok
 
 
-def _grads(cfg, q, k, v, op="forward"):
+def _grads(cfg, q, k, v, op="forward", lengths=None):
     def loss(q, k, v):
         if op == "prefill":
-            out, state = attention.prefill(q, k, v, cfg)
+            out, state = attention.prefill(q, k, v, cfg, lengths=lengths)
+            if lengths is not None:  # outputs past a row's end are padding
+                ok = jnp.arange(q.shape[2]) < lengths[:, None]
+                out = out * ok[:, None, :, None]
             return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(state.s)
         out = attention.forward(q, k, v, cfg)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
 def _assert_grads_close(got, want, *, rtol=3e-3, atol=1e-3):
@@ -70,18 +73,27 @@ def test_grad_parity_vs_reference(backend, causal):
     _assert_grads_close(_grads(cfg, q, k, v), _grads(ref_cfg, q, k, v))
 
 
-@pytest.mark.parametrize("backend", ["pallas_chunk", "fused_causal",
-                                     "pallas_fused", "xla_chunked"])
-def test_grad_parity_through_prefill(backend):
+@pytest.mark.parametrize("backend,n,lengths", [
+    *(pytest.param(b, 32, None, id=b) for b in
+      ["pallas_chunk", "fused_causal", "pallas_fused", "xla_chunked"]),
+    # fused_causal again under one chunk of 16, at no multiple of it, and
+    # packed (rows ending at 23, 37 and 5)
+    pytest.param("fused_causal", 9, None, id="fused_causal-n9"),
+    pytest.param("fused_causal", 37, None, id="fused_causal-n37"),
+    pytest.param("fused_causal", 37, (23, 37, 5), id="fused_causal-packed"),
+])
+def test_grad_parity_through_prefill(backend, n, lengths):
     """Gradients flow through the (out, FlowState) prefill op too."""
-    q, k, v = _qkv(1, 1, 4, 2, 32, 8)
+    q, k, v = _qkv(1, 1 if lengths is None else len(lengths), 4, 2, n, 8)
     cfg = FlowConfig(causal=True, strict_causal=True, chunk_size=16,
                      backend=backend)
-    if not _applicable(cfg, q, k, v, op="prefill"):
-        pytest.skip(f"{backend} prefill not applicable")
+    op = "prefill" if lengths is None else "prefill_packed"
+    if not _applicable(cfg, q, k, v, op=op):
+        pytest.skip(f"{backend} {op} not applicable")
+    lens = None if lengths is None else jnp.asarray(lengths)
     ref_cfg = dataclasses.replace(cfg, backend="xla_cumsum")
-    _assert_grads_close(_grads(cfg, q, k, v, op="prefill"),
-                        _grads(ref_cfg, q, k, v, op="prefill"))
+    _assert_grads_close(_grads(cfg, q, k, v, op="prefill", lengths=lens),
+                        _grads(ref_cfg, q, k, v, op="prefill", lengths=lens))
 
 
 @pytest.mark.parametrize("backend,causal", [("pallas_chunk", True),
