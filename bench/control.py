@@ -59,9 +59,8 @@ def serve_readings(run: harness.Run, seeds, seconds: float):
             engine, schedule, seconds, run.mix.get("drain_s", 60.0))
         sample = serve.sample_finished(reqs, seed,
                                        run.mix["check"]["requests"])
-        model = run.config["model"]
-        prog = serve.token_gaps(params, model, sample)
-        ctrl = serve.token_gaps(params, model, sample, quant=QUANT)
+        prog = serve.token_gaps(params, run.config, sample)
+        ctrl = serve.token_gaps(params, run.config, sample, quant=QUANT)
         yield {"seed": seed, "requests": len(sample),
                "tokens": int(sum(len(g) for g in prog)),
                "program": float(max(g.max() for g in prog)),
@@ -118,9 +117,11 @@ def main(argv=None) -> int:
     cell = next(w for w in spec["workloads"] if w["name"] == args.workload)
     conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
     mix = traffic.load_mix(cell["traffic"])
+    config = harness.load_json(ROOT / conf["file"])
+    harness.reference(config)  # a missing reference fails before the chip
     devices = harness.require_chips(cell["chips"])
     harness.configure_cache()
-    run = harness.Run(cell=cell, config=harness.load_json(ROOT / conf["file"]),
+    run = harness.Run(cell=cell, config=config,
                       mix=mix, limits=harness.load_json(
                           harness.BENCH / "limits" / f"{cell['name']}.json"),
                       seed=args.seeds[0],

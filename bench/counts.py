@@ -23,12 +23,63 @@ def kv_heads(m: dict) -> int:
     return m.get("n_kv_heads") or m["n_heads"]
 
 
-def layer_matmul_params(m: dict) -> int:
-    """Weights one layer multiplies by: q/k/v/o projections and the FFN."""
-    d, hd = m["d_model"], head_dim(m)
-    attn = 2 * d * m["n_heads"] * hd + 2 * d * kv_heads(m) * hd
-    ffn = d * m["d_ff"] * (3 if m["act"] == "swiglu" else 2)
-    return attn + ffn
+def attn_dims(m: dict) -> tuple[int, int, int, int]:
+    """(kv heads, query heads a kv head serves, key width, value width) of
+    a layer's flow attention.  Latent attention (``mla``) gives each query
+    head its own keys, ``nope + rope`` wide, and values ``v_head_dim``
+    wide."""
+    a = m.get("mla")
+    if a:
+        return (m["n_heads"], 1, a["nope_head_dim"] + a["rope_head_dim"],
+                a["v_head_dim"])
+    hkv, hd = kv_heads(m), head_dim(m)
+    return hkv, m["n_heads"] // hkv, hd, hd
+
+
+def attn_params(m: dict) -> int:
+    """Projection weights of one attention layer, laid out as the program
+    lays them out: q/k/v/o heads, or with ``mla`` the query projection
+    (``wq``, or ``q_down`` and ``q_up`` under a query rank), ``kv_down``
+    onto the latent and the rotary key, ``kv_up`` from the latent to every
+    head's keys and values, and ``wo``."""
+    d, h = m["d_model"], m["n_heads"]
+    a = m.get("mla")
+    if not a:
+        hd = head_dim(m)
+        return 2 * d * h * hd + 2 * d * kv_heads(m) * hd
+    qk, r = a["nope_head_dim"] + a["rope_head_dim"], a["q_lora_rank"]
+    q = d * r + r * h * qk if r else d * h * qk
+    kv = (d * (a["kv_lora_rank"] + a["rope_head_dim"])
+          + a["kv_lora_rank"] * h * (a["nope_head_dim"] + a["v_head_dim"]))
+    return q + kv + h * a["v_head_dim"] * d
+
+
+def ffn_params(m: dict, width: int) -> int:
+    """Weights of one feed-forward network ``width`` wide."""
+    return m["d_model"] * width * (3 if m["act"] == "swiglu" else 2)
+
+
+def layer_params(m: dict, i: int) -> float:
+    """Weights a token passes through in layer ``i``: attention, then a
+    dense FFN ``d_ff`` wide in the first ``n_dense_layers`` layers and in
+    every layer of a model without ``moe``, else the expert layer: the
+    router over ``moe.router_width`` experts (absent: ``n_experts``), the
+    ``n_shared`` experts, and ``top_k * n_experts / router_width`` routed
+    ones, the experts held here (``n_experts``) that a token reaches on
+    average."""
+    e = m.get("moe")
+    if not e or i < m.get("n_dense_layers", 0):
+        return attn_params(m) + ffn_params(m, m["d_ff"])
+    scored = e.get("router_width") or e["n_experts"]
+    routed = e["top_k"] * e["n_experts"] / scored
+    return (attn_params(m) + m["d_model"] * scored
+            + (e["n_shared"] + routed)
+            * ffn_params(m, e["d_ff_expert"] or m["d_ff"]))
+
+
+def layers_params(m: dict) -> float:
+    """Weights a token passes through in all layers, the head left out."""
+    return sum(layer_params(m, i) for i in range(m["n_layers"]))
 
 
 def head_params(m: dict) -> int:
@@ -81,38 +132,36 @@ def flow_decode(bh: int, g: int, d: int, dv: int,
 def attn_flops_per_token(m: dict) -> float:
     """Forward Flow-Attention FLOPs per position of one layer, chunked."""
     c = m["attention"].get("chunk_size", 128)
-    hkv, hd = kv_heads(m), head_dim(m)
-    g = m["n_heads"] // hkv
-    return hkv * flow_chunk_flops(g, c, hd, hd) / c
+    hkv, g, dk, dv = attn_dims(m)
+    return hkv * flow_chunk_flops(g, c, dk, dv) / c
 
 
 def decode_attn_flops_per_token(m: dict) -> float:
     """Forward Flow-Attention FLOPs of one decoded token in one layer."""
-    hkv, hd = kv_heads(m), head_dim(m)
-    g = m["n_heads"] // hkv
-    return flow_decode(hkv, g, hd, hd)[0]
+    hkv, g, dk, dv = attn_dims(m)
+    return flow_decode(hkv, g, dk, dv)[0]
 
 
 def decode_token_flops(m: dict) -> float:
     """Model FLOPs of one decoded token: every layer, then the head."""
-    per_layer = 2 * layer_matmul_params(m) + decode_attn_flops_per_token(m)
-    return m["n_layers"] * per_layer + 2 * head_params(m)
+    return (2 * layers_params(m)
+            + m["n_layers"] * decode_attn_flops_per_token(m)
+            + 2 * head_params(m))
 
 
 def prefill_flops(m: dict, tokens: int, prompts: int) -> float:
     """Model FLOPs of prefilling ``tokens`` real prompt tokens (padding
     excluded) of ``prompts`` prompts; the head runs once per prompt, at its
     last position."""
-    per_token = m["n_layers"] * (2 * layer_matmul_params(m)
-                                 + attn_flops_per_token(m))
+    per_token = 2 * layers_params(m) + m["n_layers"] * attn_flops_per_token(m)
     return tokens * per_token + prompts * 2 * head_params(m)
 
 
 def train_token_flops(m: dict) -> float:
     """Model FLOPs of one trained token, forward and backward, no recompute:
-    6 per matmul weight (head included) plus three times the forward
-    attention."""
-    weights = m["n_layers"] * layer_matmul_params(m) + head_params(m)
+    6 per matmul weight it passes (head included) plus three times the
+    forward attention."""
+    weights = layers_params(m) + head_params(m)
     return 6 * weights + 3 * m["n_layers"] * attn_flops_per_token(m)
 
 

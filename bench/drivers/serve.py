@@ -18,7 +18,6 @@ import time
 import numpy as np
 
 from bench import harness, stats, traffic
-from bench.reference import flow_lm
 
 EXPECTED_BACKENDS = {"prefill_packed": "pallas_fused",
                      "decode": "pallas_decode"}
@@ -226,10 +225,11 @@ def sample_finished(reqs, seed: int, n: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def token_gaps(params, model: dict, sample, quant=None, pad: int = 1024):
+def token_gaps(params, config: dict, sample, quant=None, pad: int = 1024):
     """For each sampled request, the gap by which each served token's
     reference logit lies below the reference's best at that position, as a
-    share of the largest |logit| there.
+    share of the largest |logit| there.  The reference is the one the
+    configuration file ``config`` names.
 
     With ``quant`` set the reference also runs in that lower precision
     (the control), and the gap read is that of the token the lower
@@ -237,6 +237,7 @@ def token_gaps(params, model: dict, sample, quant=None, pad: int = 1024):
     the program's gap reads."""
     import jax.numpy as jnp
 
+    ref_lm, model = harness.reference(config), config["model"]
     out = []
     for r in sample:
         gen = np.asarray(r.generated, np.int32)
@@ -246,12 +247,12 @@ def token_gaps(params, model: dict, sample, quant=None, pad: int = 1024):
         toks[0, : len(seq)] = seq
         start = len(r.prompt) - 1
         rows = slice(start, start + len(gen))
-        ref = flow_lm.forward(params, jnp.asarray(toks), model)[0, rows]
+        ref = ref_lm.forward(params, jnp.asarray(toks), model)[0, rows]
         if quant is None:
             picked = jnp.asarray(gen)
         else:
-            low = flow_lm.forward(params, jnp.asarray(toks), model,
-                                  quant=quant)[0, rows]
+            low = ref_lm.forward(params, jnp.asarray(toks), model,
+                                 quant=quant)[0, rows]
             picked = jnp.argmax(low, axis=-1)
         best = ref.max(axis=-1)
         got = jnp.take_along_axis(ref, picked[:, None], axis=-1)[:, 0]
@@ -282,7 +283,7 @@ def run(r: harness.Run) -> harness.Outcome:
     harness.log(step_summary(steps, logs, window_s))
     sample = sample_finished(reqs, r.seed, mix["check"]["requests"])
     del engine
-    gaps = token_gaps(params, r.config["model"], sample)
+    gaps = token_gaps(params, r.config, sample)
     worst = float(max((g.max() for g in gaps), default=float("nan")))
     served = sum(len(g) for g in gaps)
     harness.log(f"checked {len(sample)} requests, {served} served tokens")

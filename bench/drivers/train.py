@@ -16,7 +16,6 @@ import time
 import numpy as np
 
 from bench import harness, traffic
-from bench.reference import flow_lm
 
 CHECK_STEPS = 3
 # steps the host may run ahead of the device: about six seconds of work
@@ -164,12 +163,13 @@ def readings(prog: dict, ref: dict, b1: float) -> dict:
 
 
 def reference(run: harness.Run, p0, batches, quant=None) -> dict:
-    """The plain reference's first three steps from the same weights."""
+    """The first three steps of the plain reference the configuration
+    names, from the same weights."""
     import jax
     import jax.numpy as jnp
 
     params = jax.device_put(jax.tree.map(jnp.asarray, p0), run.devices[0])
-    losses, g1, p3 = flow_lm.adamw_steps(
+    losses, g1, p3 = harness.reference(run.config).adamw_steps(
         params, batches[:CHECK_STEPS], run.config["model"],
         run.mix["optimizer"], quant=quant)
     return {"losses": losses, "g1": jax.device_get(g1),

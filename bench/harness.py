@@ -16,6 +16,8 @@ import os
 import pathlib
 import sys
 import time
+import types
+import typing
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -72,12 +74,52 @@ def load_json(path: pathlib.Path) -> dict:
 
 
 def model_config(model: dict):
-    """The program's ``ModelConfig`` for a configuration file's ``model``."""
-    from repro.config import AttentionConfig, ModelConfig
+    """The program's ``ModelConfig`` for a configuration file's ``model``.
 
-    kw = dict(model)
-    kw["attention"] = AttentionConfig(**kw.get("attention", {}))
-    return ModelConfig(**kw)
+    Every nested group (``attention``, ``moe``, ``mla``, ``rglru``,
+    ``ssd``) becomes its dataclass, and a list becomes a tuple where the
+    field is one; the field types are read from the dataclasses.  A key
+    the program does not have fails with its name."""
+    from repro.config import ModelConfig
+
+    return _dataclass_of(ModelConfig, model, "model")
+
+
+def _dataclass_of(cls, values: dict, where: str):
+    hints = typing.get_type_hints(cls)
+    fields = {f.name for f in dataclasses.fields(cls)}
+    for key in values:
+        if key not in fields:
+            raise ValueError(f"bench: {where}.{key}: {cls.__name__} has no "
+                             f"field {key!r}")
+    return cls(**{k: _value_of(hints[k], v, f"{where}.{k}")
+                  for k, v in values.items()})
+
+
+def _value_of(hint, value, where: str):
+    """``value`` from JSON as the field typed ``hint`` holds it."""
+    if typing.get_origin(hint) in (typing.Union, types.UnionType):
+        if value is None:
+            return None
+        hint = next(a for a in typing.get_args(hint) if a is not type(None))
+    if dataclasses.is_dataclass(hint) and isinstance(value, dict):
+        return _dataclass_of(hint, value, where)
+    if typing.get_origin(hint) is tuple and isinstance(value, list):
+        return tuple(value)
+    return value
+
+
+def reference(config: dict):
+    """The plain reference module a configuration file names under
+    ``reference``: ``bench/reference/<name>.py`` (its interface is
+    ``bench/reference/__init__.py``'s docstring).  A missing module fails
+    with its path."""
+    name = config["reference"]
+    path = BENCH / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: the configuration's reference {name!r} "
+                         f"has no module: {path} does not exist")
+    return importlib.import_module(f"bench.reference.{name}")
 
 
 class CompileCounter:

@@ -5,7 +5,8 @@
 The cell is the entry of ``workloads`` in ``BENCHMARK.json`` named
 ``--workload``.  Its configuration file, its traffic file
 (``bench/traffic/<traffic>.json``), the run loop of the traffic's kind
-(``bench/drivers/<driver>.py``), its limits (``bench/limits/<cell>.json``)
+(``bench/drivers/<driver>.py``), its limits (``bench/limits/<cell>.json``),
+the plain reference its configuration names (``bench/reference/<name>.py``)
 and a reader per per-layer metric (``bench/metrics/<metric>.py``) are all
 found by name; adding a cell, a mix or a metric adds files and entries.
 
@@ -77,6 +78,8 @@ def main(argv=None) -> int:
     mix = traffic.load_mix(cell["traffic"])
     limits = harness.load_json(harness.BENCH / "limits"
                                / f"{cell['name']}.json")
+    config = harness.load_json(ROOT / conf["file"])
+    harness.reference(config)  # a missing reference fails before the chip
     devices = harness.require_chips(cell["chips"])
     harness.configure_cache()
 
@@ -90,7 +93,7 @@ def main(argv=None) -> int:
     driver = harness.load_module(harness.BENCH / "drivers"
                                  / f"{mix['driver']}.py")
     out = driver.run(harness.Run(
-        cell=cell, config=harness.load_json(ROOT / conf["file"]), mix=mix,
+        cell=cell, config=config, mix=mix,
         limits=limits, seed=args.seed, seconds=args.seconds,
         trace_dir=trace_dir, t0=T0, devices=devices))
 

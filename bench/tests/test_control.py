@@ -14,8 +14,9 @@ control = harness.load_module(harness.BENCH / "control.py", "bench_control")
 
 
 def _run(mix, model, cell):
-    return harness.Run(cell={"name": cell}, config={"model": model}, mix=mix,
-                       limits={}, seed=3, seconds=1.5, trace_dir=None,
+    return harness.Run(cell={"name": cell},
+                       config={"model": model, "reference": "flow_lm"},
+                       mix=mix, limits={}, seed=3, seconds=1.5, trace_dir=None,
                        t0=time.perf_counter(), devices=jax.devices())
 
 

@@ -1,6 +1,9 @@
-"""A traffic file and a metric reader dropped in by name are found with no
-edit to ``bench/run.py``."""
+"""A traffic file, a metric reader and a plain reference dropped in by
+name are found with no edit to ``bench/run.py``."""
 import json
+import re
+
+import pytest
 
 from bench import harness, traffic
 
@@ -37,3 +40,33 @@ def test_every_named_file_exists():
         mix = traffic.load_mix(w["traffic"])
         assert (harness.BENCH / "drivers" / f"{mix['driver']}.py").is_file()
         assert (harness.BENCH / "limits" / f"{w['name']}.json").is_file()
+
+
+@pytest.mark.parametrize("path", sorted((harness.BENCH / "configs").glob(
+    "*.json")), ids=lambda p: p.stem)
+def test_every_configuration_names_an_existing_reference(path):
+    config = harness.load_json(path)
+    assert (harness.BENCH / "reference"
+            / f"{config['reference']}.py").is_file()
+    assert callable(harness.reference(config).forward)
+
+
+def test_missing_reference_fails_before_the_chip(tmp_path, monkeypatch):
+    spec = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    cell = spec["workloads"][0]
+    conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    config = dict(harness.load_json(harness.ROOT / conf["file"]),
+                  reference="no_such_reference")
+    (tmp_path / conf["file"]).parent.mkdir(parents=True)
+    (tmp_path / conf["file"]).write_text(json.dumps(config))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    monkeypatch.setattr(run, "ROOT", tmp_path)
+
+    def chip(_chips):
+        raise AssertionError("the chip was looked for")
+
+    monkeypatch.setattr(harness, "require_chips", chip)
+    want = str(harness.BENCH / "reference" / "no_such_reference.py")
+    with pytest.raises(SystemExit, match=re.escape(want)):
+        run.main(["--workload", cell["name"], "--seed", "1",
+                  "--seconds", "1"])

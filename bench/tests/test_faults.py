@@ -26,7 +26,8 @@ TRAIN_MIX = {"driver": "train", "batch": 4, "seq": 64, "distinct_batches": 4,
 
 def _run(mix, model, cell, seconds=1.5):
     limits = harness.load_json(harness.BENCH / "limits" / f"{cell}.json")
-    r = harness.Run(cell={"name": cell}, config={"model": model}, mix=mix,
+    r = harness.Run(cell={"name": cell},
+                    config={"model": model, "reference": "flow_lm"}, mix=mix,
                     limits=limits, seed=2**33 + 11, seconds=seconds,
                     trace_dir=None, t0=time.perf_counter(),
                     devices=jax.devices())

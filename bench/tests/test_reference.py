@@ -1,5 +1,7 @@
 """The plain reference against the program's own fp32 forward, and the
 benchmark's weights against the program's tree."""
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,21 @@ CASES = {
     "mha_gelu_layernorm": dict(TINY, n_kv_heads=4, act="gelu",
                                norm="layernorm", n_layers=3),
 }
+
+
+INTERFACE = {"forward": ["params", "tokens", "model", "quant"],
+             "adamw_steps": ["params", "batches", "model", "opt", "quant"]}
+REFERENCES = sorted(p.stem for p in (harness.BENCH / "reference").glob("*.py")
+                    if p.stem != "__init__")
+
+
+@pytest.mark.parametrize("name", REFERENCES)
+def test_every_reference_exports_the_interface(name):
+    mod = harness.reference({"reference": name})
+    for fn, params in INTERFACE.items():
+        assert list(inspect.signature(getattr(mod, fn)).parameters) == params
+        assert inspect.signature(getattr(mod, fn)).parameters[
+            "quant"].default is None
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
