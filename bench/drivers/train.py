@@ -7,6 +7,8 @@ made from the seed, and drives that same step object through its first
 three steps on three different batches: those are the steps the reference
 follows.  The window then keeps calling the same step, with up to
 ``AHEAD`` steps queued on the device, and ends with ``block_until_ready``.
+A traced run hands the compiled step's text to the scope split
+(``bench/scopes.py``) as ``step_hlo`` in its context.
 """
 from __future__ import annotations
 
@@ -59,6 +61,14 @@ def build(run: harness.Run):
     batches = [jax.device_put(b, run.devices[0])
                for b in traffic.train_batches(mix, run.seed, cfg.vocab_size)]
     return cfg, jit_step, state, state_shape, batches
+
+
+def compile_step(jit_step, state_shape, batch, traced: bool) -> str | None:
+    """Compile the step before its first call; return its optimized HLO
+    text where the run is traced, for the scope split
+    (``bench/scopes.py``), else None."""
+    compiled = jit_step.lower(state_shape, batch).compile()
+    return compiled.as_text() if traced else None
 
 
 def first_steps(jit_step, state, batches):
@@ -181,7 +191,8 @@ def run(r: harness.Run) -> harness.Outcome:
     mix = r.mix
     counter = harness.CompileCounter()
     cfg, jit_step, state, state_shape, batches = build(r)
-    jit_step.lower(state_shape, batches[0]).compile()
+    step_hlo = compile_step(jit_step, state_shape, batches[0],
+                            r.trace_dir is not None)
     state, prog = first_steps(jit_step, state, batches)
     harness.log(f"first steps: losses {prog['losses']}")
     setup_s = time.perf_counter() - r.t0
@@ -203,4 +214,4 @@ def run(r: harness.Run) -> harness.Outcome:
         memory_peak_bytes=peak,
         ctx={"kind": "train", "steps": n, "window_s": window_s,
              "tokens": tokens, "compiles": counter.count,
-             "model": r.config["model"], "mix": mix})
+             "model": r.config["model"], "mix": mix, "step_hlo": step_hlo})

@@ -48,7 +48,10 @@ def configure_cache():
     """Keep JAX's persistent compilation cache at ``<checkout>/.jax_cache``
     (a fixed path: it is part of each entry's key), caching every program,
     with no size limit: a limit makes every read and write take one file
-    lock, which the warm-up's compile threads then queue on."""
+    lock, which the warm-up's compile threads then queue on.  Op metadata
+    is part of each entry's key, so a program's compiled text always
+    carries the named scopes of the code that ran (``bench/scopes.py``),
+    never those of an entry compiled from code with other scopes."""
     import jax
 
     CACHE_DIR.mkdir(exist_ok=True)
@@ -57,6 +60,7 @@ def configure_cache():
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_compilation_cache_max_size", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def load_module(path: pathlib.Path, name: str | None = None):

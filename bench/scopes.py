@@ -29,10 +29,19 @@ metadata (``no_metadata``, which XLA inserted), ops that could not be
 joined (``unjoined``), and the time inside the step in which no op ran
 (``gaps``).  ``recompute`` overlaps the five.
 
-The compiled text comes from compiling the cell's step again after the
-window, as ``drivers/train.build`` builds it, with op metadata in the
-persistent cache's key: a cache entry compiled from code with other
-scopes (or none) is never read for it.
+Beside the classes, the same pass sums the time under every scope by
+name (``by_scope``): an op counts toward each path component that begins
+with ``step.``, so ``.../step.ffn/step.ffn.router/dot_general`` counts to
+both ``step.ffn`` and ``step.ffn.router``.  A part of a layer is named
+``step.<layer>.<part>`` and opened inside its layer's scope; its reader
+is one line, ``return scopes.scope_reading(ctx, "step.ffn.experts")``,
+and reads nothing (a null in the result) once no op carries the name.
+
+The compiled text is the step the run loop compiled and ran
+(``drivers/train.run`` puts it in the run's context as ``step_hlo``).
+The harness keeps op metadata in the persistent cache's key
+(``harness.configure_cache``), so that step is never read from an entry
+compiled from code with other scopes (or none).
 """
 from __future__ import annotations
 
@@ -43,6 +52,7 @@ import time
 from bench import harness, readers, trace
 
 CLASSES = ("mixer", "ffn", "head", "optimizer", "other")
+SCOPE = "step."  # the prefix of every program scope
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+) = (.*)$")
 _NAME = re.compile(r"%[\w.\-]+")
 _WRAPPED = re.compile(r"^[\w\-]+\((.*)\)$")
@@ -168,28 +178,43 @@ def classify(op_name: str | None, names: dict) -> tuple[str, bool, bool]:
     return found, "transpose(" in path, "rematted_computation" in path
 
 
+def scopes_of(op_name: str | None) -> tuple[str, ...]:
+    """Every program scope on the path of an op with metadata ``op_name``
+    (its first path, for a merged ``a;b``), outermost first, each once."""
+    if not op_name:
+        return ()
+    path = _split_top(op_name, ";")[0]
+    return tuple(dict.fromkeys(c for c in components(path)
+                               if c.startswith(SCOPE)))
+
+
 def _kind(op: trace.Op, hlo: dict, names: dict):
-    """(``pass`` entry or None, ``other`` subfield or None, recompute) of
-    a traced op; None for a control-flow container."""
+    """(``pass`` entry or None, ``other`` subfield or None, recompute,
+    scopes on its path) of a traced op; None for a control-flow
+    container."""
     if trace._is_container(op):
         return None
     ins = hlo.get(op.name)
     if ins is None or ins.canon != _canon(op.text.split(" = ", 1)[1]):
-        return None, "unjoined", False
+        return None, "unjoined", False, ()
     cls, bwd, remat = classify(ins.op_name, names)
     field = None
     if cls not in CLASSES:
         cls, field = "other", cls
-    return (cls, "bwd" if bwd else "fwd"), field, remat
+    return ((cls, "bwd" if bwd else "fwd"), field, remat,
+            scopes_of(ins.op_name))
 
 
 def split(tr: trace.Trace, hlo: dict, names: dict) -> dict | None:
     """Nanoseconds per execution of the train step in the traced window,
-    by class and pass; None without an execution.
+    by class and pass, and by scope name and pass; None without an
+    execution.
 
     Returns {"steps": n, "step": module ns, "pass": {(class, "fwd"|"bwd"):
-    ns}, "sub": {subfield: ns}, "recompute": {class: ns}}; the ``pass``
-    entries and ``unjoined`` and ``gaps`` (in ``sub``) sum to ``step``.
+    ns}, "sub": {subfield: ns}, "recompute": {class: ns}, "by_scope":
+    {scope: {"fwd": ns, "bwd": ns}}}; the ``pass`` entries and
+    ``unjoined`` and ``gaps`` (in ``sub``) sum to ``step``, and
+    ``by_scope`` holds the scopes that some op of the window carries.
     """
     execs = [m for m in tr.modules
              if m[0] == readers.TRAIN and trace.in_window(tr, m[1], m[2])]
@@ -200,6 +225,7 @@ def split(tr: trace.Trace, hlo: dict, names: dict) -> dict | None:
     sub = dict.fromkeys(("embed", "norm", "unscoped", "no_metadata",
                          "unjoined", "gaps"), 0)
     recompute = dict.fromkeys(CLASSES, 0)
+    by_scope: dict[str, dict] = {}
     op_ns = 0
     kinds: dict[str, tuple | None] = {}  # an instruction runs many times
     j = 0
@@ -212,7 +238,7 @@ def split(tr: trace.Trace, hlo: dict, names: dict) -> dict | None:
             kinds[op.text] = _kind(op, hlo, names)
         if kinds[op.text] is None:
             continue
-        entry, field, remat = kinds[op.text]
+        entry, field, remat, scoped = kinds[op.text]
         op_ns += op.dur
         if entry is not None:
             acc[entry] += op.dur
@@ -220,13 +246,18 @@ def split(tr: trace.Trace, hlo: dict, names: dict) -> dict | None:
             sub[field] += op.dur
         if remat:
             recompute[entry[0] if entry else "other"] += op.dur
+        for name in scoped:
+            by_scope.setdefault(name, {"fwd": 0, "bwd": 0})[entry[1]] += \
+                op.dur
     step_ns = sum(m[2] for m in execs)
     sub["gaps"] = step_ns - op_ns
     n = len(execs)
     return {"steps": n, "step": step_ns / n,
             "pass": {k: v / n for k, v in acc.items()},
             "sub": {k: v / n for k, v in sub.items()},
-            "recompute": {k: v / n for k, v in recompute.items()}}
+            "recompute": {k: v / n for k, v in recompute.items()},
+            "by_scope": {k: {p: v / n for p, v in d.items()}
+                         for k, d in by_scope.items()}}
 
 
 def metrics(got: dict) -> dict:
@@ -248,44 +279,42 @@ def metrics(got: dict) -> dict:
     return out
 
 
-def step_hlo(ctx) -> str:
-    """The optimized HLO text of the cell's train step, compiled as the
-    training run loop builds it (``drivers/train.build``), with op
-    metadata in the compilation cache's key."""
-    import jax
-
-    from bench.drivers import train
-
-    run = harness.Run(cell={}, config={"model": ctx["model"]},
-                      mix=ctx["mix"], limits={}, seed=0, seconds=0.0,
-                      trace_dir=None, t0=0.0, devices=jax.devices()[:1])
-    _, jit_step, _, state_shape, batches = train.build(run)
-    key = "jax_compilation_cache_include_metadata_in_key"
-    before = getattr(jax.config, key)
-    jax.config.update(key, True)
-    try:
-        return jit_step.lower(state_shape, batches[0]).compile().as_text()
-    finally:
-        jax.config.update(key, before)
-
-
-def reading(ctx, cls: str):
-    """The metric of class ``cls`` (or ``recompute``) for a traced train
-    run; None where there is nothing to read: no trace, no step in it, or
-    a program that names no scopes.  The split is made once a run and kept
-    in ``ctx``."""
-    if ctx.get("kind") != "train" or ctx.get("trace") is None:
+def _split_of(ctx) -> dict | None:
+    """The run's ``split``, made once a run and kept in ``ctx``; None
+    where there is nothing to read: not a traced train run, no compiled
+    text in the context, a program that names no scopes, or no step in
+    the trace."""
+    if (ctx.get("kind") != "train" or ctx.get("trace") is None
+            or not ctx.get("step_hlo")):
         return None
-    if "scope_metrics" not in ctx:
+    if "scope_split" not in ctx:
         names = program_scopes()
         got = None
         if names is not None:
             t0 = time.perf_counter()
-            text = step_hlo(ctx)
-            t1 = time.perf_counter()
-            got = split(ctx["trace"], parse_hlo(text), names)
-            harness.log(f"scopes: step built and compiled in {t1 - t0:.1f}s, "
-                        f"parsed and split in {time.perf_counter() - t1:.1f}s")
-        ctx["scope_metrics"] = None if got is None else metrics(got)
-    found = ctx["scope_metrics"]
-    return None if found is None else dict(found[cls])
+            got = split(ctx["trace"], parse_hlo(ctx["step_hlo"]), names)
+            harness.log(f"scopes: parsed and split in "
+                        f"{time.perf_counter() - t0:.1f}s")
+        ctx["scope_split"] = got
+    return ctx["scope_split"]
+
+
+def reading(ctx, cls: str):
+    """The metric of class ``cls`` (or ``recompute``) for a traced train
+    run; None where there is nothing to read (``_split_of``)."""
+    got = _split_of(ctx)
+    return None if got is None else metrics(got)[cls]
+
+
+def scope_reading(ctx, name: str):
+    """Device ms per step under the program scope ``name``, with its
+    forward and backward parts and its share of the step; None where
+    there is nothing to read, or where no op of the step carries
+    ``name``."""
+    got = _split_of(ctx)
+    if got is None or name not in got["by_scope"]:
+        return None
+    ms = 1e-6
+    fwd, bwd = got["by_scope"][name]["fwd"], got["by_scope"][name]["bwd"]
+    return {"value": (fwd + bwd) * ms, "fwd": fwd * ms, "bwd": bwd * ms,
+            "share": 100.0 * (fwd + bwd) / got["step"]}

@@ -4,7 +4,8 @@
         On one TPU: two train steps of the ``flowformer-lm-xla``
         configuration at 2 x 512, traced inside a ``bench.window`` span,
         written as ``<dir>/train_scopes.xplane.pb`` beside the compiled
-        step's text, ``<dir>/train_scopes.hlo.gz``.
+        step's text as a traced run takes it (``drivers/train.compile_step``
+        under ``harness.configure_cache``), ``<dir>/train_scopes.hlo.gz``.
 
     JAX_PLATFORMS=cpu python3 bench/tests/record_scopes.py hlo <batch> <seq> [--rename]
         No chip needed: the cell's train step at ``batch`` x ``seq``,
@@ -45,11 +46,13 @@ def fixture(out: pathlib.Path):
     from bench.drivers import train
 
     devices = harness.require_chips(1)
+    harness.configure_cache()
     _, jit_step, state, state_shape, batches = train.build(
         _run(2, 512, devices))
-    compiled = jit_step.lower(state_shape, batches[0]).compile()
+    # the text a traced run hands the scope split, taken as it takes it
+    text = train.compile_step(jit_step, state_shape, batches[0], True)
     for b in batches:  # warm
-        state, _ = compiled(state, b)
+        state, _ = jit_step(state, b)
     jax.block_until_ready(state)
     tmp = out / "trace"
     shutil.rmtree(tmp, ignore_errors=True)
@@ -61,14 +64,14 @@ def fixture(out: pathlib.Path):
         # that starts before the span is not a step of the window
         time.sleep(0.05)
         for b in batches:
-            state, _ = compiled(state, b)
+            state, _ = jit_step(state, b)
         jax.block_until_ready(state)
     jax.profiler.stop_trace()
     shutil.copy(next(tmp.rglob("*.xplane.pb")),
                 out / "train_scopes.xplane.pb")
     shutil.rmtree(tmp)
     (out / "train_scopes.hlo.gz").write_bytes(
-        gzip.compress(compiled.as_text().encode(), mtime=0))
+        gzip.compress(text.encode(), mtime=0))
 
 
 def hlo(batch: int, seq: int, rename: bool = False) -> str:

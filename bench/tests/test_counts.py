@@ -98,19 +98,22 @@ def test_deepseek_v2_lite_share_by_hand():
 @pytest.mark.parametrize("preset", ["deepseek_v2_lite_16b",
                                     "granite_moe_3b_a800m"])
 def test_expert_counts_match_program_weights(preset):
-    """With every expert held and reached, the weights a token passes are
-    the program's projection weights (every ``w`` leaf) and the head."""
+    """With every held expert reached, the weights a token passes are the
+    program's projection weights (every ``w`` leaf) and the head.  A token
+    reaches every held expert where it picks as many experts as the router
+    scores (``router_width``, absent or 0: ``n_experts``); ``top_k``
+    changes no weight, so it is set on the counted side alone."""
     import jax
 
     from repro.models import lm
 
     cfg = importlib.import_module(f"repro.configs.{preset}").smoke_config()
-    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-        cfg.moe, top_k=cfg.moe.n_experts))
     shapes = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), cfg))
     leaves = jax.tree_util.tree_leaves_with_path(shapes)
     w = sum(x.size for path, x in leaves if path[-1].key == "w")
     model = dataclasses.asdict(cfg)
+    moe = model["moe"]
+    moe["top_k"] = moe.get("router_width") or moe["n_experts"]
     assert w + shapes["head"]["table"].size == (
         counts.layers_params(model) + counts.head_params(model))
 

@@ -6,11 +6,12 @@ recorded on one TPU v5e (two train steps of ``flowformer-lm-xla`` at 2 x
 ``bench/tests/record_scopes.py fixture`` records both)."""
 import gzip
 import pathlib
+import time
 
 import jax
 import pytest
 
-from bench import readers, scopes, trace
+from bench import harness, readers, scopes, trace
 from bench.tests.conftest import TINY
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
@@ -160,43 +161,115 @@ def test_split_counts_unjoined_ops_and_whole_steps_only():
     assert scopes.split(_trace([], window=(600, 700)), hlo, NAMES) is None
 
 
+def test_by_scope_counts_an_op_to_every_scope_on_its_path():
+    nested = COMPILED.replace('op_name="jit(train_step)/step.optimizer/add"',
+                              'op_name="jit(train_step)/step.ffn/'
+                              'step.ffn.router/add;x/step.head/add"')
+    ops = [("%fusion.7", 100, 20), ("%add.3", 120, 30), ("%mul.5", 150, 40)]
+    ctx = {"kind": "train", "trace": _trace(ops), "step_hlo": nested}
+    got = scopes.split(ctx["trace"], scopes.parse_hlo(nested), NAMES)
+    # the innermost program scope decides the class; ``step.ffn.router``
+    # is none of the six, so the router's add is the FFN's
+    assert got["pass"][("ffn", "fwd")] == 30 / 2
+    assert got["pass"][("ffn", "bwd")] == 20 / 2
+    assert got["by_scope"] == {
+        "step.ffn": {"fwd": 30 / 2, "bwd": 20 / 2},
+        "step.ffn.router": {"fwd": 30 / 2, "bwd": 0},
+        "step.mixer": {"fwd": 40 / 2, "bwd": 0}}
+    assert scopes.scope_reading(ctx, "step.ffn.router") == pytest.approx(
+        {"value": 1.5e-5, "fwd": 1.5e-5, "bwd": 0, "share": 15})
+    assert scopes.scope_reading(ctx, "step.ffn")["value"] == pytest.approx(
+        scopes.reading(ctx, "ffn")["value"])
+    # merged metadata goes by its first path: no ``step.head`` op ran
+    assert scopes.scope_reading(ctx, "step.head") is None
+    assert scopes.scope_reading(ctx, "step.no_such_scope") is None
+
+
 def test_reading_is_silent_where_there_is_nothing_to_read(monkeypatch):
     tr = _trace([("%add.3", 110, 30)])
-    assert scopes.reading({"kind": "serve", "trace": tr}, "mixer") is None
-    assert scopes.reading({"kind": "train"}, "mixer") is None
-    # a program without named scopes: nothing is compiled, nothing read
-    monkeypatch.setattr(scopes, "program_scopes", lambda: None)
-    monkeypatch.setattr(scopes, "step_hlo", lambda ctx: 1 / 0)
+    ctx = {"kind": "serve", "trace": tr, "step_hlo": COMPILED}
+    assert scopes.reading(ctx, "mixer") is None
+    assert scopes.reading({"kind": "train", "step_hlo": COMPILED},
+                          "mixer") is None
+    # a traced train run whose driver handed over no compiled text
     assert scopes.reading({"kind": "train", "trace": tr}, "mixer") is None
+    assert scopes.scope_reading({"kind": "train", "trace": tr},
+                                "step.optimizer") is None
+    # a program without named scopes: nothing is read
+    monkeypatch.setattr(scopes, "program_scopes", lambda: None)
+    ctx = {"kind": "train", "trace": tr, "step_hlo": COMPILED}
+    assert scopes.reading(ctx, "mixer") is None
+    assert scopes.scope_reading(ctx, "step.optimizer") is None
 
 
 def test_reading_splits_once_a_run(monkeypatch):
     calls = []
+    real = scopes.split
 
-    def step_hlo(ctx):
+    def split(*a):
         calls.append(1)
-        return COMPILED
+        return real(*a)
 
-    monkeypatch.setattr(scopes, "step_hlo", step_hlo)
-    ctx = {"kind": "train", "trace": _trace([("%add.3", 110, 30)])}
+    monkeypatch.setattr(scopes, "split", split)
+    ctx = {"kind": "train", "trace": _trace([("%add.3", 110, 30)]),
+           "step_hlo": COMPILED}
     got = {c: scopes.reading(ctx, c) for c in scopes.CLASSES + ("recompute",)}
+    by_name = scopes.scope_reading(ctx, "step.optimizer")
     assert calls == [1]
     assert got["optimizer"]["value"] == pytest.approx(1.5e-5)
     assert set(got["other"]) >= {"embed", "norm", "unscoped", "no_metadata",
                                  "unjoined", "gaps", "fwd", "bwd", "share"}
+    assert by_name == pytest.approx(got["optimizer"])
 
 
-def test_step_hlo_is_the_run_loops_step_with_its_scopes():
-    mix = {"driver": "train", "batch": 2, "seq": 64, "distinct_batches": 1,
+def test_run_hands_the_readers_its_compiled_step(monkeypatch, tmp_path):
+    """A traced run of the training driver puts the text of the step it
+    compiled and ran in its context; the readers parse that text and never
+    build or compile the step again."""
+    from bench.drivers import train
+
+    mix = {"driver": "train", "batch": 2, "seq": 64, "distinct_batches": 4,
            "optimizer": {"kind": "adamw", "b1": 0.9, "b2": 0.95,
                          "eps": 1e-8, "weight_decay": 0.1, "grad_clip": 1.0,
                          "peak_lr": 3e-4, "warmup": 5, "total_steps": 100}}
-    key = "jax_compilation_cache_include_metadata_in_key"
-    before = getattr(jax.config, key)
-    hlo = scopes.parse_hlo(scopes.step_hlo({"model": TINY, "mix": mix}))
-    assert getattr(jax.config, key) == before
+    r = harness.Run(cell={}, config={"model": TINY, "reference": "flow_lm"},
+                    mix=mix, limits={}, seed=2**33 + 7, seconds=0.5,
+                    trace_dir=tmp_path, t0=time.perf_counter(),
+                    devices=jax.devices())
+    out = train.run(r)
+    hlo = scopes.parse_hlo(out.ctx["step_hlo"])
     found = {scopes.classify(i.op_name, NAMES)[0] for i in hlo.values()}
     assert found >= {"mixer", "ffn", "head", "optimizer", "embed", "norm"}
+
+    def build(*a, **k):
+        raise AssertionError("a reader built the train step again")
+
+    monkeypatch.setattr(train, "build", build)
+    ctx = dict(out.ctx, trace=trace.load(tmp_path), peaks={})
+    for m in ("mixer", "ffn", "head", "optimizer", "other", "recompute"):
+        reader = harness.load_module(harness.BENCH / "metrics"
+                                     / f"{m}_ms.train.py")
+        assert reader.read(ctx) is None  # a CPU trace has no device plane
+    # a trace of the step's own instructions: one op of each class
+    ops, t = [], 100
+    for line in out.ctx["step_hlo"].splitlines():
+        m = scopes._INSTR.match(line)
+        if m is None or m.group(1) not in hlo:
+            continue
+        op = trace.Op(m.group(1), readers.TRAIN, t, 10,
+                      f"{m.group(1)} = {m.group(2)}")
+        cls = scopes.classify(hlo[op.name].op_name, NAMES)[0]
+        if cls in found and not trace._is_container(op):
+            found.discard(cls)
+            ops.append(op)
+            t += 10
+    ctx = dict(out.ctx, trace=trace.Trace([(readers.TRAIN, 100, 100)], ops,
+                                          [], (0, 1000)))
+    for c in ("mixer", "ffn", "head", "optimizer"):
+        assert scopes.reading(ctx, c)["value"] == pytest.approx(1e-5)
+        assert scopes.scope_reading(ctx, f"step.{c}")["value"] == \
+            pytest.approx(1e-5)
+    assert scopes.reading(ctx, "other")["embed"] == pytest.approx(1e-5)
 
 
 @pytest.fixture(scope="module")
@@ -236,3 +309,15 @@ def test_recorded_split_partitions_the_step(recorded):
     assert m["optimizer"]["bwd"] == 0
     assert m["other"]["embed"] > 0 and m["other"]["norm"] > 0
     assert 0 < m["recompute"]["value"] < step_ms
+
+
+def test_recorded_scopes_by_name_match_the_classes(recorded):
+    tr, hlo = recorded
+    got = scopes.split(tr, hlo, NAMES)
+    for c in ("mixer", "ffn", "head", "optimizer"):
+        assert got["by_scope"][f"step.{c}"] == {
+            p: got["pass"][(c, p)] for p in ("fwd", "bwd")}
+    for field in ("embed", "norm"):
+        by_name = got["by_scope"][f"step.{field}"]
+        assert by_name["fwd"] + by_name["bwd"] == got["sub"][field]
+    assert set(got["by_scope"]) == set(NAMES)
