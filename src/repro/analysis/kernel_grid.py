@@ -125,6 +125,16 @@ def _boundary_gather():
     return call
 
 
+def _fused_ce_fwd():
+    from repro.kernels.fused_ce.fused_ce import fused_ce_fwd_call
+    return fused_ce_fwd_call
+
+
+def _fused_ce_bwd():
+    from repro.kernels.fused_ce.bwd import fused_ce_bwd_call
+    return fused_ce_bwd_call
+
+
 def _qkv():
     return (_z((_BH, _G, _N, _D)), _z((_BH, _N, _D)), _z((_BH, _N, _DV)))
 
@@ -166,6 +176,18 @@ def _paged_pools_quant():
             _z((p, hkv, page, 1)), _z((p, hkv, page, 1)),
             _z((2, 3), jnp.int32))
 
+
+# the vocab head: T=64 tokens against a 512-row vocabulary whose last 128
+# rows are padding, in (32, 256) tiles
+_CE_T, _CE_V = 64, 512
+
+
+def _ce_args():
+    return (_z((_CE_T, _D), jnp.bfloat16), _z((_CE_V, _D), jnp.bfloat16),
+            _z((_CE_T, 1), jnp.int32))
+
+
+_CE_STATICS = dict(tm=32, tv=256, v=384, interpret=True)
 
 _FLOW_STATICS = dict(eps=1e-6, phi="sigmoid", use_allocation=True)
 
@@ -214,6 +236,10 @@ GRID: tuple[GridEntry, ...] = (
     GridEntry("boundary_gather", _boundary_gather,
               lambda: (_z((2, _N, 8)), _z((2,), jnp.int32)),
               dict(interpret=True)),
+    GridEntry("fused_ce_fwd_call", _fused_ce_fwd, _ce_args, _CE_STATICS),
+    GridEntry("fused_ce_bwd_call", _fused_ce_bwd,
+              lambda: (*_ce_args(), _z((_CE_T, 1)), _z((_CE_T, 1))),
+              _CE_STATICS),
 )
 
 
@@ -235,6 +261,11 @@ def _vjp_nc():
 def _vjp_nc_fused():
     from repro.attention.vjp import _flow_nc_fused_fwd
     return _flow_nc_fused_fwd
+
+
+def _vjp_fused_ce():
+    from repro.kernels.fused_ce.ops import _nll_fwd
+    return _nll_fwd
 
 
 def _vjp_ssd():
@@ -266,4 +297,8 @@ VJP_ENTRIES: tuple[VjpEntry, ...] = (
              lambda: (_z((_BH, _NB, 64)), _z((_BH, _NB, 1)),
                       _z((_BH, _NB, 16)), _z((_BH, _NB, 16))),
              statics=(128, True), seq_len=_NB),
+    VjpEntry("fused_ce_nll", _vjp_fused_ce,
+             lambda: (_z((_NB, _D), jnp.bfloat16), _z((_NB, _D), jnp.bfloat16),
+                      _z((_NB,), jnp.int32)),
+             statics=(True,), seq_len=_NB),
 )

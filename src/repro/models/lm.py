@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro import scopes
 from repro.config import ModelConfig
+from repro.core.losses import token_nll
 from repro.layers.embeddings import embed, embedding_init, unembed
 from repro.layers.ffn import ffn, ffn_init
 from repro.layers.mixer import (
@@ -123,21 +124,14 @@ def _embed_inputs(params, inputs: Array, cfg: ModelConfig, dtype) -> Array:
     return inputs.astype(dtype)  # stub frontend: precomputed embeddings
 
 
-def forward(
-    params,
-    inputs: Array,
-    cfg: ModelConfig,
-    *,
-    positions: Array | None = None,
-    dtype=jnp.bfloat16,
-    plan=None,
-):
-    """inputs: int tokens (B, N) or stub embeddings (B, N, d).
+def _head_params(params, cfg: ModelConfig) -> dict:
+    """The vocab head's table: the input embedding's when tied."""
+    return params["embed"] if cfg.tie_embeddings else params["head"]
 
-    ``plan`` (an ``attention.ExecutionPlan``) carries the execution context
-    built once at step construction — mesh/axis sharding for context
-    parallelism, gradient needs — instead of per-call kwargs.
-    Returns (logits (B, N, vocab) fp32, aux_loss scalar)."""
+
+def _hidden(params, inputs: Array, cfg: ModelConfig, *, positions, dtype,
+            plan):
+    """The final-normed hidden states (B, N, d) and the aux loss."""
     b = inputs.shape[0]
     n = inputs.shape[1]
     with jax.named_scope(scopes.EMBED):
@@ -189,24 +183,44 @@ def forward(
 
     with jax.named_scope(scopes.NORM):
         x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    return x, aux_total
+
+
+def forward(
+    params,
+    inputs: Array,
+    cfg: ModelConfig,
+    *,
+    positions: Array | None = None,
+    dtype=jnp.bfloat16,
+    plan=None,
+):
+    """inputs: int tokens (B, N) or stub embeddings (B, N, d).
+
+    ``plan`` (an ``attention.ExecutionPlan``) carries the execution context
+    built once at step construction — mesh/axis sharding for context
+    parallelism, gradient needs — instead of per-call kwargs.
+    Returns (logits (B, N, vocab) fp32, aux_loss scalar)."""
+    x, aux = _hidden(params, inputs, cfg, positions=positions, dtype=dtype,
+                     plan=plan)
     with jax.named_scope(scopes.HEAD):
-        logits = unembed(head, x, softcap=cfg.logit_softcap)
-    return logits, aux_total
+        logits = unembed(_head_params(params, cfg), x,
+                         softcap=cfg.logit_softcap)
+    return logits, aux
 
 
 def loss_fn(params, batch: dict, cfg: ModelConfig, *, dtype=jnp.bfloat16,
             plan=None):
     """batch: {"inputs": tokens/embeds, "targets": (B,N) int, "mask": (B,N)}."""
-    logits, aux = forward(params, batch["inputs"], cfg, dtype=dtype,
-                          positions=batch.get("positions"), plan=plan)
+    x, aux = _hidden(params, batch["inputs"], cfg, dtype=dtype,
+                     positions=batch.get("positions"), plan=plan)
     targets = batch["targets"]
     mask = batch.get("mask")
     if mask is None:
         mask = jnp.ones_like(targets, jnp.float32)
     with jax.named_scope(scopes.HEAD):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        nll = token_nll(x, _head_params(params, cfg), targets,
+                        softcap=cfg.logit_softcap, plan=plan)
     denom = jnp.maximum(mask.sum(), 1.0)
     ce = (nll * mask).sum() / denom
     loss = ce + aux
@@ -282,7 +296,7 @@ def prefill(params, inputs: Array, cfg: ModelConfig, max_len: int,
                         cfg.act, cfg.moe)
             x = x + y2
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    head = _head_params(params, cfg)
     if lengths is None:
         x_last = x[:, -1:]
     else:  # each row's boundary token, not the padded tail
@@ -329,7 +343,7 @@ def decode(params, token: Array, caches, cfg: ModelConfig, pos: Array,
                         cfg.act, cfg.moe)
             x = x + y2
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    head = _head_params(params, cfg)
     logits = unembed(head, x, softcap=cfg.logit_softcap)
     return logits, new_caches
 
@@ -373,7 +387,7 @@ def verify(params, tokens: Array, caches, cfg: ModelConfig, pos: Array,
                         cfg.act, cfg.moe)
             x = x + y2
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    head = _head_params(params, cfg)
     logits = unembed(head, x, softcap=cfg.logit_softcap)
     return logits, pending
 

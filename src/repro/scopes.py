@@ -17,6 +17,8 @@ NORM = "step.norm"
 MIXER = "step.mixer"
 FFN = "step.ffn"
 HEAD = "step.head"
+# the fused projection and cross-entropy, a part of ``HEAD``
+HEAD_FUSED = "step.head.fused"
 OPTIMIZER = "step.optimizer"
 
 ALL = (EMBED, NORM, MIXER, FFN, HEAD, OPTIMIZER)

@@ -1,7 +1,7 @@
 """Ahead-of-time compiles of the main-path kernels for a TPU v5e chip.
 
-The flow kernels of flowformer-lm's serving and training path and the
-paged-decode gathers compile here.  The ``ssd_chunk`` kernel and the packed
+The flow kernels of flowformer-lm's serving and training path, its vocab
+head's fused cross-entropy and the paged-decode gathers compile here.  The ``ssd_chunk`` kernel and the packed
 hybrid boundary gather do not compile for v5e yet and are not covered.
 
 Interpret mode runs a kernel's math but not the chip's compiler, which
@@ -189,3 +189,42 @@ def test_paged_gather_compiles(one_chip, quant):
             pool, pool, scale, scale, table)
     else:
         _compile(paged_gather, pool, pool, table)
+
+
+def test_fused_ce_compiles(one_chip):
+    """The vocab head's fused cross-entropy, forward and backward, at the
+    training cell's widths: 6 x 8192 tokens, d 512, a 32768-row
+    vocabulary.  Its kernels carry the head's scopes, so a device trace
+    attributes them, and no fp32 (tokens x vocab) buffer is left."""
+    import re
+
+    from repro import scopes
+    from repro.kernels.fused_ce import token_nll
+
+    t, d, v = 6 * 8192, 512, 32768
+
+    def loss(x, table, targets):
+        with jax.named_scope(scopes.HEAD):
+            return jnp.mean(token_nll(x, table.astype(x.dtype), targets))
+
+    x = jax.ShapeDtypeStruct((t, d), jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((v, d), jnp.float32, sharding=one_chip)
+    targets = jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, table, targets).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 2, "one forward and one backward kernel"
+    for ln in kernels:
+        path = re.search(r'op_name="([^"]*)"', ln).group(1)
+        parts = set()
+        for c in path.split("/"):
+            while (m := re.match(r"^[\w\-]+\((.*)\)$", c)) is not None:
+                c = m.group(1)
+            parts.add(c)
+        assert {scopes.HEAD, scopes.HEAD_FUSED} <= parts, path
+    assert f"f32[{t},{v}]" not in text
+    # the backward's bf16 cotangent is the one (tokens x vocab) buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * t * v
