@@ -11,8 +11,7 @@ Two layers keep the conventions PR 1-8 established mechanically true
   wrapper over :mod:`repro.analysis.kernel_grid` and statically checks
   alias maps, VMEM footprints, and the fp32-accumulation invariant;
   :mod:`repro.analysis.capability_audit` cross-checks both registries
-  and the prose capability tables; :mod:`repro.analysis.hlo` gates
-  canonical-plan HLO metrics against a committed baseline.
+  and the prose capability tables.
 
 CLI: ``python -m repro.analysis`` (blocking in CI's ``analysis`` job).
 """

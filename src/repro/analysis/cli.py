@@ -2,16 +2,12 @@
 
 Default run = flowlint over ``src/repro`` + the kernel auditor + the
 capability auditor; exit status 1 if any error-severity finding is not
-grandfathered in the baseline.  ``--hlo`` additionally compiles the
-canonical plans and gates their HLO metrics against
-``benchmarks/hlo_baseline.json`` (15% drift, like the regression gate).
+grandfathered in the baseline.
 
 Examples::
 
     python -m repro.analysis                  # lint + kernel + capability
     python -m repro.analysis --no-audit       # AST lint only (fast)
-    python -m repro.analysis --hlo            # + HLO structural gate
-    python -m repro.analysis --hlo --update-hlo-baseline
     python -m repro.analysis --json           # machine-readable findings
 """
 from __future__ import annotations
@@ -50,10 +46,6 @@ def main(argv: list[str] | None = None) -> int:
                     help="skip the AST lint layer")
     ap.add_argument("--no-audit", action="store_true",
                     help="skip the kernel + capability auditors")
-    ap.add_argument("--hlo", action="store_true",
-                    help="also run the HLO structural-drift gate")
-    ap.add_argument("--update-hlo-baseline", action="store_true",
-                    help="refresh benchmarks/hlo_baseline.json and exit clean")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit findings as JSON instead of text")
     args = ap.parse_args(argv)
@@ -68,10 +60,6 @@ def main(argv: list[str] | None = None) -> int:
 
         findings += audit_kernels()
         findings += audit_capabilities(root)
-    if args.hlo or args.update_hlo_baseline:
-        from repro.analysis.hlo import audit_hlo
-
-        findings += audit_hlo(update=args.update_hlo_baseline)
 
     findings = apply_baseline(findings, load_baseline(args.baseline))
     errors = [f for f in findings if f.severity == "error"]

@@ -134,8 +134,8 @@ register it — no call site changes anywhere::
 
     register_backend("my_kernel", MyKernel(), before="fused_causal")
 
-``before=`` positions the backend in the auto order; benchmark sweeps pick
-it up by name immediately (``benchmarks/efficiency_table3.py --backends``).
+``before=`` positions the backend in the auto order; it can be pinned by
+name (``FlowConfig(backend="my_kernel")``) immediately.
 """
 from repro.core.flow_attention import FlowConfig
 

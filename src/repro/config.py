@@ -203,10 +203,3 @@ class ShapeSpec:
     global_batch: int
     kind: Literal["train", "prefill", "decode"]
 
-
-LM_SHAPES = (
-    ShapeSpec("train_4k", 4096, 256, "train"),
-    ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    ShapeSpec("decode_32k", 32768, 128, "decode"),
-    ShapeSpec("long_500k", 524288, 1, "decode"),
-)

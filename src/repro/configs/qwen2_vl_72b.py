@@ -1,8 +1,8 @@
 """Qwen2-VL 72B backbone [arXiv:2409.12191]: 80L, d_model 8192, 64 heads
 (GQA kv=8), d_ff 29568, vocab 152064 — SwiGLU, RMSNorm, M-RoPE
 (sections t/h/w = 16/24/24 frequency pairs of the 128-dim head).  The ViT
-patch frontend is a STUB: ``input_specs()`` provides patch embeddings and
-3D positions."""
+patch frontend is a STUB: ``launch.specs.train_inputs`` / ``prefill_inputs``
+provide patch embeddings (and, for training, 3D positions)."""
 import dataclasses
 
 from repro.config import AttentionConfig, ModelConfig

@@ -1,6 +1,7 @@
 """Whisper-small backbone [arXiv:2212.04356]: enc-dec, 12L each, d_model 768,
 12 heads, d_ff 3072, vocab 51865 — GELU, pre-LN.  The strided-conv audio
-stem is a STUB: ``input_specs()`` provides precomputed frame embeddings."""
+stem is a STUB: ``launch.specs.train_inputs`` / ``prefill_inputs`` provide
+precomputed frame embeddings."""
 import dataclasses
 
 from repro.config import AttentionConfig, ModelConfig

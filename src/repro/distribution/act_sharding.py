@@ -2,15 +2,15 @@
 
 Baseline behaviour (no context set) lets XLA's SPMD propagation choose
 activation shardings; on several cells it picks feature-sharded residuals
-with per-layer all-gathers (see EXPERIMENTS.md §Perf before/after).  When a
-policy is activated, model code pins the residual stream to
+with per-layer all-gathers.  When a policy is activated, model code pins
+the residual stream to
 
     (batch over DP axes, sequence replicated-or-SP, features replicated)
 
 at block boundaries, which turns the per-layer resharding traffic into the
 canonical TP pattern (reduce-scatter/all-gather around the two matmul pairs
-only).  Thread-local so the dry-run can lower baseline and optimized
-variants of the same model in one process.
+only).  Thread-local so baseline and optimized variants of the same model
+can be lowered in one process.
 """
 from __future__ import annotations
 

@@ -1,12 +1,7 @@
-"""Production meshes.
-
-Single pod: (data=16, model=16) = 256 chips (TPU v5e pod slice).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the ``pod`` axis
-composes with ``data`` for the data-parallel gradient reduction (DCN-ish
-outer ring) while ``model`` stays intra-pod (ICI).
+"""Device meshes.
 
 These are FUNCTIONS, not module constants — importing this module never
-touches jax device state (required by the dry-run contract).
+touches jax device state.
 
 Every mesh here has ``Auto`` axes: the sharding rules
 (``with_sharding_constraint`` in ``launch/steps.py``, the context-parallel
@@ -17,12 +12,6 @@ from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):

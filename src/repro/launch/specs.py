@@ -1,4 +1,4 @@
-"""ShapeDtypeStruct input specs for every (arch × shape) dry-run cell.
+"""ShapeDtypeStruct input specs for the step builders, per (arch x shape).
 
 No device allocation anywhere: model params/caches come from
 ``jax.eval_shape`` over the init functions; inputs are explicit
@@ -17,16 +17,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.config import LM_SHAPES, ModelConfig, ShapeSpec
+from repro.config import ModelConfig, ShapeSpec
 
 SDS = jax.ShapeDtypeStruct
-
-
-def shape_by_name(name: str) -> ShapeSpec:
-    for s in LM_SHAPES:
-        if s.name == name:
-            return s
-    raise KeyError(name)
 
 
 def train_inputs(cfg: ModelConfig, shape: ShapeSpec) -> dict[str, Any]:
@@ -78,14 +71,6 @@ def decode_inputs(cfg: ModelConfig, shape: ShapeSpec) -> dict[str, Any]:
         else SDS((b, 1), jnp.int32)
     )
     return {"token": token, "caches": caches, "pos": SDS((), jnp.int32)}
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> dict[str, Any]:
-    if shape.kind == "train":
-        return train_inputs(cfg, shape)
-    if shape.kind == "prefill":
-        return prefill_inputs(cfg, shape)
-    return decode_inputs(cfg, shape)
 
 
 def params_shape(cfg: ModelConfig):

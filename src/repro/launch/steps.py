@@ -1,8 +1,8 @@
 """Step builders: distributed train_step / serve_step per architecture.
 
-These produce the exact jitted computations that the dry-run lowers and
-the real launchers (train.py / serve.py) execute.  Each builder constructs
-ONE attention ``ExecutionPlan`` at build time (gradient needs for the train
+These produce the exact jitted computations that the launchers (train.py /
+serve.py) and the chip benchmark execute.  Each builder constructs ONE
+attention ``ExecutionPlan`` at build time (gradient needs for the train
 step, the mesh/axis ``ShardSpec`` for sequence-parallel prefill) and the
 ``repro/attention`` registry resolves it — step builders decide
 distribution (sharding, microbatching, sequence parallelism), never which
@@ -108,7 +108,7 @@ class RunPlan:
     param_mode: str = "replicated"  # replicated (dp) | fsdp (zero-sharded)
     microbatch: int = 0
     optimizer: str = "adamw"
-    # §Perf opt bundle (baseline False; see EXPERIMENTS.md §Perf)
+    # opt-in perf bundle (baseline False)
     fused_vg: bool = False    # one value_and_grad pass instead of two fwd
     act_shard: bool = False   # pin residual activations to (dp, None, None)
 

@@ -1,10 +1,11 @@
 """Whisper-style encoder-decoder backbone (conv frontend is a stub).
 
-``input_specs()`` for this family provides *precomputed frame embeddings*
-(B, N_enc, d_model) — the strided-conv audio stem is out of scope per the
-assignment.  Encoder self-attention is non-causal; decoder self-attention is
-causal; cross-attention is non-causal flow attention with n != m (queries =
-decoder, keys/values = encoder), exercising the rectangular case of Eq. 4.
+``launch.specs.train_inputs`` / ``prefill_inputs`` for this family provide
+*precomputed frame embeddings* (B, N_enc, d_model) — the strided-conv audio
+stem is out of scope per the assignment.  Encoder self-attention is
+non-causal; decoder self-attention is causal; cross-attention is non-causal
+flow attention with n != m (queries = decoder, keys/values = encoder),
+exercising the rectangular case of Eq. 4.
 
 Serving: cross-attention decode treats each new token as the single sink of
 a fresh non-causal flow attention against the cached encoder keys/values

@@ -44,7 +44,7 @@ class TrainConfig:
     compute_dtype: Any = jnp.bfloat16
     # §Perf iteration 1: fuse loss+grad into one value_and_grad pass
     # (baseline False reproduces the paper-faithful first implementation,
-    # which lowered an extra metrics forward — see EXPERIMENTS.md §Perf)
+    # which lowered an extra metrics forward)
     fused_value_grad: bool = False
 
 

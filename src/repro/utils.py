@@ -109,10 +109,9 @@ def global_norm(tree: PyTree) -> jax.Array:
 # Dtype accounting
 # ---------------------------------------------------------------------------
 #: Canonical HLO-mnemonic -> bytes-per-element table.  This is THE byte
-#: table: ``launch.hlo_analysis`` parses optimized HLO against its keys,
-#: ``serving.quant.pool_bytes`` and the ``repro.analysis`` kernel auditor
-#: account device buffers through :func:`dtype_bytes`.  Keeping one copy
-#: means a new dtype (fp8 variants, fp4, ...) lands everywhere at once.
+#: table: ``serving.quant.pool_bytes`` and the ``repro.analysis`` kernel
+#: auditor account device buffers through :func:`dtype_bytes`.  Keeping one
+#: copy means a new dtype (fp8 variants, fp4, ...) lands everywhere at once.
 HLO_DTYPE_BYTES: dict[str, int] = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,

@@ -1,9 +1,9 @@
 """Execute every fenced ``python`` block in the repo's markdown docs.
 
-The docs CI job runs this so README / docs examples cannot rot: each
-markdown file's ```` ```python ```` blocks run top-to-bottom in ONE shared
-namespace per file (so a later block may use names an earlier block
-defined), with assertions live.  A block whose last preceding non-blank
+``tests/test_docs.py`` runs this once per file, so README / docs examples
+cannot rot: each markdown file's ```` ```python ```` blocks run
+top-to-bottom in ONE shared namespace per file (so a later block may use
+names an earlier block defined), with assertions live.  A block whose last preceding non-blank
 line is the marker comment
 
     <!-- notest -->

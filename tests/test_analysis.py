@@ -378,43 +378,6 @@ def test_docs_drift_is_caught(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# HLO gate
-# ---------------------------------------------------------------------------
-def test_hlo_compare_flags_drift_and_structure():
-    from repro.analysis.hlo import compare_to_baseline
-
-    base = {"plans": {"train": {
-        "dot_flops": 100.0, "hbm_bytes": 100.0, "collective_bytes": 0.0,
-        "collectives": {}}}}
-    same = {"train": {"dot_flops": 110.0, "hbm_bytes": 100.0,
-                      "collective_bytes": 0.0, "collectives": {}}}
-    assert compare_to_baseline(same, base) == []
-
-    drift = {"train": {"dot_flops": 200.0, "hbm_bytes": 100.0,
-                       "collective_bytes": 0.0, "collectives": {}}}
-    f = compare_to_baseline(drift, base)
-    assert [x.rule for x in f] == ["HL001"] and "dot_flops" in f[0].message
-
-    newcoll = {"train": {"dot_flops": 100.0, "hbm_bytes": 100.0,
-                         "collective_bytes": 0.0,
-                         "collectives": {"all-reduce": 64.0}}}
-    f = compare_to_baseline(newcoll, base)
-    assert any("collective structure" in x.message for x in f)
-
-    f = compare_to_baseline({}, base)
-    assert any("no longer produced" in x.message for x in f)
-
-
-def test_committed_hlo_baseline_exists():
-    from repro.analysis.hlo import DEFAULT_BASELINE
-
-    data = json.loads(DEFAULT_BASELINE.read_text())
-    assert set(data["plans"]) == {"train", "serve"}
-    for plan in data["plans"].values():
-        assert plan["dot_flops"] > 0
-
-
-# ---------------------------------------------------------------------------
 # Repo-wide + CLI
 # ---------------------------------------------------------------------------
 def test_repo_lint_is_clean():
