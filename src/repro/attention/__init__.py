@@ -165,6 +165,7 @@ from repro.attention.api import (
     verify_step,
 )
 from repro.attention.dots import causal_dot, causal_dot_grouped
+from repro.attention.fused import flow_sums_bytes
 from repro.attention.recurrent import FlowState, init_state, select_state
 from repro.attention._pallas import chunked_causal_dot_pallas
 from repro.attention import backends as _backends  # registers the builtins
@@ -195,5 +196,6 @@ __all__ = [
     "select_state",
     "causal_dot",
     "causal_dot_grouped",
+    "flow_sums_bytes",
     "chunked_causal_dot_pallas",
 ]

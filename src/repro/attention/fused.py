@@ -21,12 +21,21 @@ chunks; nothing runs chunk after chunk, forward or backward.  The returned
 state is the totals — the last position's sums and the sum of the chunk
 states — which is exactly the decode ``FlowState``, so prefill hands serving
 its state for free.
+
+The four running sums, the flows before their division or clip, ``z``
+and the chunk states are tagged ``scopes.FLOW_SUMS`` (``checkpoint_name``,
+an identity): a per-layer checkpoint whose policy names the tag keeps them
+for the backward instead of recomputing the prefix matmuls and the flows'
+contractions; outside such a checkpoint the tag does nothing.
+``flow_sums_bytes`` counts what the tag keeps.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from repro import scopes
 from repro.core.flow_attention import FlowConfig, _group, _ungroup, phi_map
 from repro.attention.recurrent import FlowState
 
@@ -44,6 +53,25 @@ def effective_chunk(n: int, chunk_size: int) -> int:
 def padded_len(n: int, chunk: int) -> int:
     """``n`` rounded up to the next multiple of ``chunk``."""
     return -(-n // chunk) * chunk
+
+
+def flow_sums_bytes(b: int, h: int, n: int, d: int, dv: int,
+                    chunk_size: int) -> int:
+    """Device bytes that keeping one layer's ``scopes.FLOW_SUMS`` residuals
+    costs at a (b, h, n, d) -> dv call: twice their fp32 count, which is
+    four running sums (b, h, n_pad, d), five per-token values (b, h,
+    n_pad) and the chunk states (b, h, nc, d, dv).  The factor is
+    measured: at the paper LM's 6 x 8192 tokens keeping them added 5.53 GB
+    of temporaries to the step compiled for a v5e over six layers, against
+    2.77 GB counted.  With grouped kv heads it is an upper bound."""
+    c = effective_chunk(n, chunk_size)
+    n_pad = padded_len(n, c)
+    return 2 * 4 * b * h * (4 * n_pad * d + 5 * n_pad
+                            + n_pad // c * d * dv)
+
+
+def _keep(x: Array) -> Array:
+    return checkpoint_name(x, scopes.FLOW_SUMS)
 
 
 def _pad_seq(x: Array, n_pad: int, axis: int) -> Array:
@@ -147,20 +175,20 @@ def fused_causal_forward(
     ok = row_ok.reshape(b, 1, nc, c)
 
     # (1) flows from the running source/sink sums
-    k_csum = _prefix(ks, 2)  # (B,H,nc,c,d)
-    q_csum = _prefix(qs.sum(axis=2), 2)
-    sink_in = normal_k / jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
-                                    k_csum + eps)
-    src_out = normal_q / jnp.einsum("bhncd,bhncd->bhnc", ks + eps,
-                                    q_csum + eps)
+    k_csum = _keep(_prefix(ks, 2))  # (B,H,nc,c,d)
+    q_csum = _keep(_prefix(qs.sum(axis=2), 2))
+    sink_in = normal_k / _keep(jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
+                                          k_csum + eps))
+    src_out = normal_q / _keep(jnp.einsum("bhncd,bhncd->bhnc", ks + eps,
+                                          q_csum + eps))
 
     # (2) conservation refinement
-    ko_csum = _prefix(ks * src_out[..., None], 2)
-    cons_sink = jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
-                           ko_csum + eps) / normal_q
-    qi_csum = _prefix((qs * sink_in[..., None]).sum(axis=2), 2)
-    cons_src = jnp.clip(
-        jnp.einsum("bhncd,bhncd->bhnc", ks + eps, qi_csum + eps) / normal_k,
+    ko_csum = _keep(_prefix(ks * src_out[..., None], 2))
+    cons_sink = _keep(jnp.einsum("bhgncd,bhncd->bhgnc", qs + eps,
+                                 ko_csum + eps) / normal_q)
+    qi_csum = _keep(_prefix((qs * sink_in[..., None]).sum(axis=2), 2))
+    cons_src = jnp.clip(_keep(
+        jnp.einsum("bhncd,bhncd->bhnc", ks + eps, qi_csum + eps) / normal_k),
         -1.0, 1.0,
     )
 
@@ -171,7 +199,7 @@ def fused_causal_forward(
         alloc = jnp.ones_like(cons_sink)
     # e masked past each row's boundary so z freezes with the sums
     e = jnp.exp(cons_src) * ok  # in [1/e, e] while valid
-    z = _prefix(e, 2)  # (B,H,nc,c)
+    z = _keep(_prefix(e, 2))  # (B,H,nc,c)
     v_w = vs * e[..., None]
 
     # (4) aggregation: intra-chunk tril matmul + the (D,Dv) state of every
@@ -185,7 +213,7 @@ def fused_causal_forward(
                        preferred_element_type=f32)
     states = jnp.einsum("bhnjd,bhnje->bhnde", ks, v_w,
                         preferred_element_type=f32)  # (B,H,nc,D,Dv)
-    s_before = _exclusive(states, 2)
+    s_before = _keep(_exclusive(states, 2))
     inter = jnp.einsum("bhgnid,bhnde->bhgnie", q_in, s_before,
                        preferred_element_type=f32)
     out = (intra + inter) * (normal_k / z)[:, :, None, ..., None]

@@ -3,7 +3,9 @@
 Layer stacking uses ``lax.scan`` over repeats of the block *pattern* (one
 period = e.g. ("rglru", "rglru", "attn") for RecurrentGemma) with stacked
 parameters, keeping HLO size O(pattern) instead of O(layers); remainder
-layers run unrolled.  Remat wraps each period when ``cfg.remat``.
+layers run unrolled.  Remat wraps each period when ``cfg.remat``; the
+checkpoint keeps the flow mixer's running sums where they fit
+(``_remat_policy``) and recomputes everything else in the backward.
 
 Which mechanism runs a block comes from ``cfg.block_kind`` (the single
 source of truth) through the ``repro/layers/mixer`` SequenceMixer registry
@@ -24,7 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import scopes
+from repro import attention, scopes
 from repro.config import ModelConfig
 from repro.core.losses import token_nll
 from repro.layers.embeddings import embed, embedding_init, unembed
@@ -118,6 +120,34 @@ def init(key, cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+# The per-layer checkpoint keeps the flow mixer's tagged residuals only
+# while all layers' together fit in this many bytes: 3/8 of the 16 GB of a
+# TPU v5e, the smallest chip the program trains on.  The rest holds the
+# weights and optimizer state and the step's other temporaries: 0.63 and
+# 4.07 GB for the paper LM at 6 x 8192 tokens (compiled for a v5e), whose
+# kept residuals cost 5.53 GB more.
+KEEP_BYTES = 6e9
+
+
+def _remat_policy(cfg: ModelConfig, b: int, n: int):
+    """What the per-layer checkpoint keeps at b rows of n tokens: the flow
+    mixer's running sums (``scopes.FLOW_SUMS``) while every flow layer's
+    fit ``KEEP_BYTES``, else nothing.  Mixers other than ``fused_causal``
+    tag nothing, so for them the two policies give the same program.  b
+    is the batch the loss sees, under a data-parallel mesh the global one,
+    so there the choice errs toward keeping nothing."""
+    n_flow = 0
+    if cfg.attention.kind == "flow":  # "local" slots too run flow attention
+        n_flow = sum(cfg.block_kind(i) in ("attn", "local")
+                     for i in range(cfg.n_layers))
+    kept = n_flow * attention.flow_sums_bytes(
+        b, cfg.n_heads, n, cfg.dim_head, cfg.dim_head,
+        cfg.attention.chunk_size)
+    if kept > KEEP_BYTES:
+        return None
+    return jax.checkpoint_policies.save_only_these_names(scopes.FLOW_SUMS)
+
+
 def _embed_inputs(params, inputs: Array, cfg: ModelConfig, dtype) -> Array:
     if inputs.dtype in (jnp.int32, jnp.int64):
         return embed(params["embed"], inputs, dtype)
@@ -144,6 +174,7 @@ def _hidden(params, inputs: Array, cfg: ModelConfig, *, positions, dtype,
 
     period = len(cfg.pattern)
     aux_total = jnp.zeros((), jnp.float32)
+    policy = _remat_policy(cfg, b, n)
 
     if "scan" in params:
         n_rep = jax.tree.leaves(params["scan"][0])[0].shape[0]
@@ -157,7 +188,7 @@ def _hidden(params, inputs: Array, cfg: ModelConfig, *, positions, dtype,
             return x, aux
 
         if cfg.remat:
-            period_body = jax.checkpoint(period_body)
+            period_body = jax.checkpoint(period_body, policy=policy)
 
         def scan_body(carry, layer_params):
             x, aux = carry
@@ -177,7 +208,7 @@ def _hidden(params, inputs: Array, cfg: ModelConfig, *, positions, dtype,
             f = functools.partial(_block_apply, kind=kind, cfg=cfg,
                                   positions=positions, plan=plan)
             if cfg.remat:
-                f = jax.checkpoint(f)
+                f = jax.checkpoint(f, policy=policy)
             x, a = f(bp, x)
             aux_total = aux_total + a
 

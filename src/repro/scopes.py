@@ -22,3 +22,10 @@ HEAD_FUSED = "step.head.fused"
 OPTIMIZER = "step.optimizer"
 
 ALL = (EMBED, NORM, MIXER, FFN, HEAD, OPTIMIZER)
+
+# Not a scope: the ``jax.ad_checkpoint.checkpoint_name`` of the flow
+# mixer's running sums, flow contractions, ``z`` and chunk states
+# (``attention/fused.py``), which the per-layer checkpoint keeps for the
+# backward where they fit (``models/lm.py``).  A tag reaches no
+# ``op_name``.
+FLOW_SUMS = "flow_sums"

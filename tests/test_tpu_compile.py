@@ -228,3 +228,41 @@ def test_fused_ce_compiles(one_chip):
     assert f"f32[{t},{v}]" not in text
     # the backward's bf16 cotangent is the one (tokens x vocab) buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * t * v
+
+
+def test_train_step_keeps_flow_sums(topo, one_chip, monkeypatch):
+    """The training cell's step: the paper LM, 6 x 8192 tokens, attention
+    on ``fused_causal``, the vocab head on its fused kernel as on a TPU.
+    Its per-layer checkpoint keeps the flow mixer's running sums, so the
+    backward's recompute of the mixer holds none of their chunk-local
+    prefix matmuls (``attention/fused._local_prefix``, ``...j,ij->...i``),
+    and the kept sums leave the step's temporaries under 11 GB of the
+    chip's 16."""
+    import dataclasses
+
+    from jax.sharding import Mesh
+
+    from repro.config import ShapeSpec
+    from repro.configs import get_config
+    from repro.core import losses
+    from repro.launch.steps import build_train_step
+
+    fused = losses._fused
+    monkeypatch.setattr(losses, "_fused",
+                        lambda h, s, p, backend: fused(h, s, p, "tpu"))
+    base = get_config("flowformer_lm")
+    cfg = dataclasses.replace(
+        base, max_seq_len=8192, remat=True, scan_layers=True,
+        attention=dataclasses.replace(base.attention, backend="fused_causal"))
+    shape = ShapeSpec("train-8k", 8192, 6, "train")
+    step, state_shape, _, _ = build_train_step(
+        cfg, shape, Mesh([[topo.devices[0]]], ("data", "model")),
+        train_overrides={"fused_value_grad": True})
+    tok = jax.ShapeDtypeStruct((6, 8192), jnp.int32)
+    compiled = step.lower(state_shape, {"inputs": tok,
+                                        "targets": tok}).compile()
+    text = compiled.as_text()
+    prefix = "step.mixer/...j,ij->...i/"
+    assert f'{prefix}dot_general"' in text  # the forward computes them
+    assert f"rematted_computation/{prefix}" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 11e9
